@@ -11,8 +11,10 @@ The paper's third transformation pillar, measured end-to-end:
   ``StreamEngine(replicas=k)`` for k = 1, 2, 4 (the batch-parallel
   farm) and through :func:`repro.parallel.replicate.replicate_app`
   (spatial row partitioning), recording measured throughput next to
-  the model's predicted linear scaling.  Multi-device rows run in a
-  subprocess with forced host devices, like tests/test_distribution.
+  the model's predicted linear scaling.  On an accelerator the rows run
+  in this process on the visible devices (a child could not claim
+  them); on the CPU they run in a child with four forced host devices,
+  like tests/test_distribution.
 
 ``--smoke`` (CI) asserts the two correctness properties cheaply: the
 vector-factor sweep is monotone-feasible with exact ``128*vf`` minor
@@ -136,54 +138,67 @@ def calibration_row(drift, report, calibrate, drift_report) -> dict:
     return row
 
 
-_REPLICA_SUB = r"""
+def replica_sweep(backend: str, counts: tuple[int, ...] = (1, 2, 4)
+                  ) -> list[dict]:
+    """Engine-farm and spatial replication rows for each replica count.
+
+    Runs in the process that holds the devices; every count must be at
+    most the visible device count.
+    """
+    from repro.parallel.replicate import replicate_app
+    from repro.runtime import StreamEngine
+
+    H, W, N = 64, 256, 96
+    rng = np.random.default_rng(0)
+    frames = [rng.normal(size=(H, W)).astype(np.float32) for _ in range(N)]
+    g = build_app("filter_chain", H, W)
+    app = compile_graph(build_app("filter_chain", H, W), backend=backend)
+    ref = np.asarray(app(img=frames[0])["out"])
+
+    rows = []
+    for k in counts:
+        with StreamEngine(backend=backend, max_batch=8, replicas=k,
+                          max_queue=N) as eng:
+            eng.submit(g, {"img": frames[0]}).result()        # warm
+            t0 = time.perf_counter()
+            hs = [eng.submit(g, {"img": f}) for f in frames]
+            outs = [h.result() for h in hs]
+            dt = time.perf_counter() - t0
+            rep = eng.report(n_items=N)
+        assert np.array_equal(np.asarray(outs[0]["out"]), ref), k
+        mod = next(iter(rep["modeled"].values()))
+        rows.append({"name": f"parallel_engine_r{k}", "us": dt / N * 1e6,
+                     "replicas": k, "throughput_rps": N / dt,
+                     "throughput_per_replica_rps": N / dt / k,
+                     "modeled_scaling": mod.get("replica_scaling_modeled",
+                                                1.0),
+                     "h": H, "w": W, "n": N, "backend": backend})
+
+    for k in counts:
+        rapp = replicate_app(app, k)
+        out = np.asarray(rapp(img=frames[0])["out"])
+        assert np.array_equal(out, ref), k
+        t0 = time.perf_counter()
+        for f in frames[:32]:
+            np.asarray(rapp(img=f)["out"])
+        dt = time.perf_counter() - t0
+        rows.append({"name": f"parallel_spatial_r{k}", "us": dt / 32 * 1e6,
+                     "replicas": k, "throughput_rps": 32 / dt,
+                     "halo_rows": rapp.halo_rows, "h": H, "w": W,
+                     "backend": backend})
+    return rows
+
+
+#: the CPU path: a child with four virtual host devices (the parent's
+#: CPU backend is already fixed at one device)
+_VIRTUAL_SUB = r"""
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
-import sys, json, time
+import sys, json
 sys.path.insert(0, "src")
-import numpy as np
-from repro.core import compile_graph
-from repro.core.apps import build_app
-from repro.parallel.replicate import replicate_app
-from repro.runtime import StreamEngine
-
-H, W, N = 64, 256, 96
-rng = np.random.default_rng(0)
-frames = [rng.normal(size=(H, W)).astype(np.float32) for _ in range(N)]
-g = build_app("filter_chain", H, W)
-app = compile_graph(build_app("filter_chain", H, W), backend="xla")
-ref = np.asarray(app(img=frames[0])["out"])
-
-rows = []
-for k in (1, 2, 4):
-    with StreamEngine(backend="xla", max_batch=8, replicas=k,
-                      max_queue=N) as eng:
-        eng.submit(g, {"img": frames[0]}).result()        # warm
-        t0 = time.perf_counter()
-        hs = [eng.submit(g, {"img": f}) for f in frames]
-        outs = [h.result() for h in hs]
-        dt = time.perf_counter() - t0
-        rep = eng.report(n_items=N)
-    assert np.array_equal(np.asarray(outs[0]["out"]), ref), k
-    mod = next(iter(rep["modeled"].values()))
-    rows.append({"name": f"parallel_engine_r{k}", "us": dt / N * 1e6,
-                 "replicas": k, "throughput_rps": N / dt,
-                 "throughput_per_replica_rps": N / dt / k,
-                 "modeled_scaling": mod.get("replica_scaling_modeled", 1.0),
-                 "h": H, "w": W, "n": N})
-
-for k in (1, 2, 4):
-    rapp = replicate_app(app, k)
-    out = np.asarray(rapp(img=frames[0])["out"])
-    assert np.array_equal(out, ref), k
-    t0 = time.perf_counter()
-    for f in frames[:32]:
-        np.asarray(rapp(img=f)["out"])
-    dt = time.perf_counter() - t0
-    rows.append({"name": f"parallel_spatial_r{k}", "us": dt / 32 * 1e6,
-                 "replicas": k, "throughput_rps": 32 / dt,
-                 "halo_rows": rapp.halo_rows, "h": H, "w": W})
-print(json.dumps(rows))
+sys.path.insert(0, ".")
+from benchmarks.bench_parallel import replica_sweep
+print(json.dumps(replica_sweep("xla")))
 """
 
 
@@ -202,7 +217,14 @@ def replica_rows(smoke: bool) -> list[dict]:
         return [{"name": "parallel_spatial_r1_smoke", "us": 0.0,
                  "replicas": 1, "bit_exact": True,
                  "halo_rows": rapp.halo_rows, "h": h, "w": w}]
-    r = subprocess.run([sys.executable, "-c", _REPLICA_SUB],
+    import jax
+    devices = jax.devices()
+    if devices[0].platform != "cpu":
+        # real devices belong to this process: a child could not get
+        # them, so the replica rows run here on what is visible
+        return replica_sweep("pallas", tuple(
+            k for k in (1, 2, 4) if k <= len(devices)))
+    r = subprocess.run([sys.executable, "-c", _VIRTUAL_SUB],
                        capture_output=True, text=True, timeout=560,
                        cwd=_ROOT)
     if r.returncode != 0:
@@ -242,4 +264,6 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     main()

@@ -55,9 +55,16 @@ def smoke() -> None:
     print(f"smoke ok: {len(MODULES)} benchmark modules import clean")
 
 
-def main() -> None:
+def main() -> int:
+    """Run every module; return the number of modules that raised.
+
+    A failed module prints an ``ERROR`` row and the sweep goes on, so
+    one broken figure does not hide the others — but the count becomes
+    the exit code, so a sweep with a failure never reads as a pass.
+    """
     import importlib
     all_rows = []
+    failed = []
     print("name,us_per_call,derived")
     for mod_name in MODULES:
         try:
@@ -66,6 +73,7 @@ def main() -> None:
         except Exception:
             print(f"{mod_name},nan,ERROR")
             traceback.print_exc()
+            failed.append(mod_name)
             continue
         for r in rows:
             us = r.get("us", r.get("cpu_wall_us", r.get("ms", 0.0)))
@@ -78,6 +86,9 @@ def main() -> None:
     os.makedirs("experiments", exist_ok=True)
     with open("experiments/bench_results.json", "w") as f:
         json.dump(all_rows, f, indent=1, default=str)
+    if failed:
+        print(f"FAILED modules: {failed}", file=sys.stderr)
+    return len(failed)
 
 
 def _trace_arg(argv: list[str]) -> str | None:
@@ -91,17 +102,21 @@ def _trace_arg(argv: list[str]) -> str | None:
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     _trace_out = _trace_arg(sys.argv)
     _tracer = None
     if _trace_out is not None:
         from repro.obs import install
         _tracer = install()
+    _n_failed = 0
     if "--smoke" in sys.argv:
         smoke()
     else:
-        main()
+        _n_failed = main()
     if _tracer is not None:
         from repro.obs import export_chrome_trace
         _payload = export_chrome_trace(_tracer, _trace_out)
         print(f"trace: {len(_payload['traceEvents'])} events "
               f"({_tracer.dropped} dropped) -> {_trace_out}")
+    sys.exit(1 if _n_failed else 0)

@@ -46,7 +46,8 @@ def _lower_pallas(group, *, backend: Backend, spec: Any,
         # custom/reduce singletons have no streaming tile structure;
         # they run as host-composed jnp on every backend
         return lower_group_xla(group, staged=False, valid_rows=valid_rows)
-    return lower_group_pallas(group, spec, vector_factor, interpret,
+    return lower_group_pallas(group, spec, interpret=interpret,
+                              vector_factor=vector_factor,
                               valid_rows=valid_rows)
 
 

@@ -66,12 +66,13 @@ class UnsupportedBackendError(GraphError):
 
 
 def _default_platform() -> str:
-    """The platform JAX would run on ("cpu" / "tpu" / "gpu" / ...)."""
-    try:
-        import jax
-        return jax.default_backend()
-    except Exception:  # pragma: no cover - no jax backend at all
-        return "cpu"
+    """The platform JAX runs on ("cpu" / "tpu" / "gpu" / ...).
+
+    A JAX that cannot initialize its backend raises here: guessing
+    "cpu" would quietly run the interpreter on a host whose chip failed.
+    """
+    import jax
+    return jax.default_backend()
 
 
 @dataclasses.dataclass(frozen=True)
@@ -238,11 +239,19 @@ class Backend:
         the current platform (a pallas backend on a real TPU runs
         compiled; everywhere else — and for the XLA backends, which
         have no pallas kernels at all — the historical interpreted
-        default is kept).
+        default is kept).  Interpreting on a platform where the
+        backend compiles natively raises
+        :class:`UnsupportedBackendError`: an interpreted kernel on the
+        chip is never what a caller measuring that chip meant.
         """
-        if interpret is not None:
-            return bool(interpret)
-        return not self.is_native()
+        mode = not self.is_native() if interpret is None else bool(interpret)
+        if mode and self.is_native():
+            raise UnsupportedBackendError(
+                f"backend {self.name!r} compiles natively on "
+                f"{_default_platform()!r}; refusing to run its kernels "
+                f"interpreted there (pass interpret=None or False)",
+                backend=self.name, missing=("compiled",))
+        return mode
 
     def resolve_donate(self, donate: bool, platform: str | None = None) -> bool:
         """Whether the batcher should build donating entries.

@@ -32,11 +32,12 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.graph import (Channel, DataflowGraph, GraphError, Stage,
                               _apply_stage_reference)
 from repro.core.schedule import FusionGroup, Schedule, build_schedule
-from repro.core.vectorize import TPUSpec, V5E, select_tile
+from repro.core.vectorize import LANE, SUBLANE, TPUSpec, select_tile
 
 __all__ = ["lower_group", "lower_graph", "BACKENDS"]
 
@@ -93,10 +94,19 @@ def _window_rows(x, valid_rows: tuple[int, int]):
 # ----------------------------------------------------------------------
 # Pallas streaming backend (the generated top-level kernel)
 # ----------------------------------------------------------------------
-def lower_group_pallas(group: FusionGroup, spec: TPUSpec = V5E,
+def lower_group_pallas(group: FusionGroup, spec: TPUSpec, *,
+                       interpret: bool,
                        vector_factor: int | None = None,
-                       interpret: bool = True,
                        valid_rows: tuple[int, int] | None = None) -> Callable:
+    """One streaming ``pallas_call`` for a fused group.
+
+    Every input block meets Mosaic's tiling rule: the halo-expanded
+    window ``(th + 2hy, tw + 2hx)`` is rounded up to whole
+    ``(SUBLANE, LANE)`` tiles, the host pad supplies the extra rows and
+    columns, and the kernel crops the window back before the first
+    stage.  ``spec.vmem_bytes`` is both the budget the tile picker fits
+    into and the scoped-VMEM limit handed to Mosaic.
+    """
     if group.is_trivial:
         raise GraphError("cannot pallas-lower a custom/reduce group")
     tile = group.tile or select_tile(group, spec, vector_factor)[0]
@@ -106,12 +116,11 @@ def lower_group_pallas(group: FusionGroup, spec: TPUSpec = V5E,
     grid = (Hp // th, Wp // tw)
     rows = valid_rows if valid_rows is not None else (0, H)
 
-    in_specs = []
-    for ch in group.inputs:
-        hy, hx = group.halo.get(ch, (0, 0))
-        in_specs.append(_element_block_spec(
-            (th + 2 * hy, tw + 2 * hx),
-            functools.partial(_in_index, th=th, tw=tw)))
+    blocks = [_input_block(tile, group.halo.get(ch, (0, 0)))
+              for ch in group.inputs]
+    in_specs = [pl.BlockSpec(tuple(pl.Element(s) for s in blk),
+                             functools.partial(_in_index, th=th, tw=tw))
+                for blk in blocks]
     out_specs = [pl.BlockSpec((th, tw), lambda i, j: (i, j))
                  for _ in group.outputs]
     out_shapes = [jax.ShapeDtypeStruct((Hp, Wp), ch.dtype)
@@ -123,17 +132,20 @@ def lower_group_pallas(group: FusionGroup, spec: TPUSpec = V5E,
 
     call = pl.pallas_call(
         kernel, grid=grid, in_specs=in_specs, out_specs=out_specs,
-        out_shape=out_shapes, interpret=interpret)
+        out_shape=out_shapes, interpret=interpret,
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=int(spec.vmem_bytes)))
 
     def run(env_in: dict[Channel, Any]) -> dict[Channel, Any]:
         ins = []
-        for ch in group.inputs:
+        for ch, (bh, bw) in zip(group.inputs, blocks):
             hy, hx = group.halo.get(ch, (0, 0))
             x = jnp.asarray(env_in[ch], dtype=ch.dtype)
             # The generated read task: zero-pad by the cumulative halo
-            # and up to a whole number of tiles; each grid step then
-            # bursts a contiguous (th+2hy, tw+2hx) block into VMEM.
-            x = jnp.pad(x, ((hy, Hp - H + hy), (hx, Wp - W + hx)))
+            # and out to where the last aligned block ends; each grid
+            # step then bursts a contiguous (bh, bw) block into VMEM.
+            x = jnp.pad(x, ((hy, Hp - th + bh - H - hy),
+                            (hx, Wp - tw + bw - W - hx)))
             ins.append(x)
         outs = call(*ins)
         if not isinstance(outs, (tuple, list)):
@@ -143,16 +155,13 @@ def lower_group_pallas(group: FusionGroup, spec: TPUSpec = V5E,
     return run
 
 
-def _element_block_spec(shape: tuple[int, int], index_map) -> pl.BlockSpec:
-    """Element-indexed BlockSpec across the pallas API generations.
-
-    jax >= 0.5 spells it ``pl.Element(n)`` per dimension; jax 0.4.x
-    spells the same semantics (index map returns element offsets, not
-    block indices) ``indexing_mode=pl.Unblocked()``.
-    """
-    if hasattr(pl, "Element"):
-        return pl.BlockSpec(tuple(pl.Element(s) for s in shape), index_map)
-    return pl.BlockSpec(shape, index_map, indexing_mode=pl.Unblocked())
+def _input_block(tile: tuple[int, int], halo: tuple[int, int]
+                ) -> tuple[int, int]:
+    """The VMEM block an input with ``halo`` is DMA'd in: the expanded
+    window rounded up to whole (SUBLANE, LANE) tiles."""
+    th, tw = tile
+    hy, hx = halo
+    return (_round_up(th + 2 * hy, SUBLANE), _round_up(tw + 2 * hx, LANE))
 
 
 def _in_index(i, j, *, th, tw):
@@ -173,7 +182,9 @@ def _group_kernel(*refs, group: FusionGroup, tile: tuple[int, int],
 
     env: dict[Channel, Any] = {}
     for ch, ref in zip(group.inputs, in_refs):
-        env[ch] = ref[...]
+        # crop the aligned block back to the halo-expanded window
+        hy, hx = group.halo.get(ch, (0, 0))
+        env[ch] = ref[...][:th + 2 * hy, :tw + 2 * hx]
 
     halo = group.halo
     for st in group.stages:  # already in topological order

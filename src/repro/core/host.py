@@ -123,8 +123,6 @@ class CompiledApp:
     # -- introspection -------------------------------------------------
     def cost(self) -> dict[str, float]:
         ca = self.compiled.cost_analysis() or {}
-        if isinstance(ca, (list, tuple)):     # jax < 0.5: per-computation list
-            ca = ca[0] if ca else {}
         return {
             "flops": float(ca.get("flops", 0.0)),
             "bytes": float(ca.get("bytes accessed", 0.0)),

@@ -2,9 +2,10 @@
 
 Every op takes ``impl=`` with three values:
 
-- ``"pallas"``     — the Pallas kernel (interpret=True on CPU; on a real
-                     TPU backend set ``interpret=False`` via
-                     ``repro.kernels.ops.INTERPRET``)
+- ``"pallas"``     — the Pallas kernel: compiled on a TPU, interpreted
+                     elsewhere (the registered ``pallas`` backend's
+                     ``resolve_interpret(None)``, the same decision the
+                     dataflow path makes)
 - ``"ref"``        — the pure-jnp oracle from :mod:`repro.kernels.ref`
 - ``"auto"``       — pallas on TPU, ref elsewhere (the dry-run path:
                      the XLA lowering is structurally equivalent and
@@ -17,6 +18,7 @@ from __future__ import annotations
 
 import jax.numpy as jnp
 
+from repro.backends import resolve as _resolve
 from repro.backends import use_pallas_kernels as _use_pallas
 from repro.kernels import ref as _ref
 from repro.kernels.decode_attention import decode_attention as _decode_pallas
@@ -26,13 +28,14 @@ from repro.kernels.ssd_scan import ssd_scan as _ssd_pallas
 
 __all__ = ["attention", "decode_attention", "mlp", "ssd", "rmsnorm"]
 
-#: flip to False when running on real TPU hardware
-INTERPRET = True
-
 # impl= resolution ("pallas" | "ref" | "auto") lives in the backend
 # registry (repro.backends.use_pallas_kernels): "auto" asks whether the
 # registered pallas backend is native on this platform — the same
 # device probe the dataflow stack uses, instead of a local copy.
+
+
+def _interpret() -> bool:
+    return _resolve("pallas").resolve_interpret(None)
 
 
 def rmsnorm(x, w, eps: float = 1e-6):
@@ -45,7 +48,7 @@ def attention(q, k, v, bias=None, causal=True, impl: str = "auto",
     if _use_pallas(impl):
         return _flash_pallas(q, k, v, bias=bias, causal=causal,
                              block_q=block_q, block_k=block_k, scale=scale,
-                             interpret=INTERPRET)
+                             interpret=_interpret())
     return _ref.flash_attention_ref(q, k, v, bias=bias, causal=causal,
                                     scale=scale)
 
@@ -55,7 +58,7 @@ def decode_attention(q, k, v, bias=None, impl: str = "auto",
     """q: (B, Hq, Dk); k: (B, Hkv, S, Dk); v: (B, Hkv, S, Dv)."""
     if _use_pallas(impl):
         return _decode_pallas(q, k, v, bias=bias, block_k=block_k,
-                              scale=scale, interpret=INTERPRET)
+                              scale=scale, interpret=_interpret())
     return _ref.decode_attention_ref(q, k, v, bias=bias, scale=scale)
 
 
@@ -67,7 +70,7 @@ def mlp(x, w_norm, w_gate, w_up, w_down, eps: float = 1e-6,
     if _use_pallas(impl):
         y = _mlp_pallas(x2, w_norm, w_gate, w_up, w_down, eps=eps,
                         block_t=block_t, block_f=block_f,
-                        interpret=INTERPRET)
+                        interpret=_interpret())
     else:
         y = _ref.fused_mlp_ref(x2, w_norm, w_gate, w_up, w_down, eps=eps)
     return y.reshape(*lead, x.shape[-1])
@@ -93,7 +96,7 @@ def ssd(x, dt, A, B, C, chunk: int = 64, impl: str = "auto",
                 "pallas ssd_scan does not take init_state; use impl='ref' "
                 "for continuation (decode prefill hand-off)")
         y, fs = _ssd_pallas(x, dt, A, B, C, chunk=chunk,
-                            interpret=INTERPRET)
+                            interpret=_interpret())
     else:
         y, fs = _ref.ssd_scan_ref(x, dt, A, B, C, chunk=chunk,
                                   init_state=init_state)

@@ -1,7 +1,11 @@
 import os
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
                            + " --xla_force_host_platform_device_count=512").strip()
 # ^ MUST precede any jax import: jax locks the device count on first init.
+# The dry-run compiles for 512 virtual host devices and never needs a
+# chip; pinning the CPU here (inherited by the per-cell children) keeps
+# it off any accelerator another process holds.
 
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell.
 

@@ -16,6 +16,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import ARCHS, get_config, get_smoke
+from repro.launch.compile_cache import use_compile_cache
+from repro.launch.mesh import make_mesh
 from repro.models import model as M
 from repro.runtime.steps import make_decode_step, make_prefill_step
 
@@ -32,12 +34,13 @@ def main() -> None:
     ap.add_argument("--mesh-data", type=int, default=0)
     ap.add_argument("--mesh-model", type=int, default=1)
     args = ap.parse_args()
+    use_compile_cache()
 
     cfg = get_smoke(args.arch) if args.smoke else get_config(args.arch)
     mesh = None
     if args.mesh_data:
-        mesh = jax.make_mesh((args.mesh_data, args.mesh_model),
-                             ("data", "model"))
+        mesh = make_mesh((args.mesh_data, args.mesh_model),
+                         ("data", "model"))
     params = M.init(cfg, jax.random.PRNGKey(0))
     B = args.batch
     max_len = args.prompt_len + args.gen_len + 8

@@ -16,6 +16,8 @@ import jax
 
 from repro.configs import ARCHS, get_config, get_smoke
 from repro.data.pipeline import SyntheticLM
+from repro.launch.compile_cache import use_compile_cache
+from repro.launch.mesh import make_mesh
 from repro.optim.adamw import AdamWConfig
 from repro.runtime.steps import train_state_shardings
 from repro.runtime.trainer import Trainer, TrainerConfig
@@ -39,6 +41,7 @@ def main() -> None:
     ap.add_argument("--ckpt-every", type=int, default=50)
     ap.add_argument("--compress-grads", action="store_true")
     args = ap.parse_args()
+    use_compile_cache()
 
     cfg = get_smoke(args.arch) if args.smoke else get_config(args.arch)
     print(f"{cfg.name}: {cfg.n_params()/1e6:.1f}M params, "
@@ -46,8 +49,8 @@ def main() -> None:
 
     mesh = state_sh = None
     if args.mesh_data:
-        mesh = jax.make_mesh((args.mesh_data, args.mesh_model),
-                             ("data", "model"))
+        mesh = make_mesh((args.mesh_data, args.mesh_model),
+                         ("data", "model"))
         state_sh = train_state_shardings(cfg, mesh)
 
     data = SyntheticLM(vocab_size=cfg.vocab_size, seq_len=args.seq,

@@ -13,8 +13,8 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-from repro.parallel._compat import shard_map
 
 __all__ = ["ring_allgather_matmul", "ring_matmul_reducescatter",
            "psum_scatter_grads", "halo_exchange_rows"]

@@ -17,8 +17,8 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-from repro.parallel._compat import shard_map
 
 __all__ = ["pipeline_apply"]
 

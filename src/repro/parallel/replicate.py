@@ -29,6 +29,7 @@ from typing import Any, Callable
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.backends import resolve, resolve_calibrated
@@ -36,7 +37,6 @@ from repro.core.fusion import lower_graph
 from repro.core.graph import Channel, DataflowGraph, GraphError
 from repro.core.host import CompiledApp, LaunchHandle
 from repro.core.schedule import Schedule, build_schedule
-from repro.parallel._compat import shard_map
 from repro.parallel.collectives import halo_exchange_rows
 from repro.parallel.sharding import replica_mesh
 

@@ -42,6 +42,7 @@ from typing import Any, Callable, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core.host import CompiledApp
@@ -84,11 +85,7 @@ class MicroBatcher:
         # The decision itself is the backend's donation policy
         # (Backend.resolve_donate); the registry default reproduces the
         # old inline probe bit-for-bit.
-        try:
-            plat = ((devices[0] if devices else jax.devices()[0])
-                    .platform)
-        except Exception:
-            plat = "cpu"
+        plat = (devices[0] if devices else jax.devices()[0]).platform
         from repro.backends import resolve
         self.backend = resolve(backend) if backend is not None else None
         if self.backend is not None:
@@ -145,11 +142,11 @@ class MicroBatcher:
 
         Keyed on ``(signature, width)`` so every bucket keeps its own
         compiled entry (``width=None`` keys a single generic entry
-        that jit re-specializes per shape).  With replicas, batch-dim
-        shardings on every input/output place each replica's rows on
-        its own device; XLA then runs the k copies of the kernel
-        concurrently with no cross-device traffic (the farm has no
-        inter-worker channels).
+        that jit re-specializes per shape).  With replicas, the vmapped
+        kernel runs under ``shard_map`` with batch-dim shardings on
+        every input/output: each replica's rows sit on its own device
+        and the k copies of the kernel run concurrently with no
+        cross-device traffic (the farm has no inter-worker channels).
         """
         key = (app.signature(), width if width is not None else -1)
         fn = self._fns.get(key)
@@ -162,13 +159,21 @@ class MicroBatcher:
         donate_argnums = (tuple(range(len(app.input_names)))
                           if donate else ())
         kwargs: dict[str, Any] = dict(donate_argnums=donate_argnums)
+        fn = jax.vmap(app.fn)
         if self._mesh is not None:
-            batch_row = NamedSharding(self._mesh, P(self.replica_axis))
+            # each replica runs the vmapped kernel on its own rows under
+            # shard_map: XLA cannot partition a Mosaic kernel itself
+            rows = P(self.replica_axis)
+            fn = shard_map(fn, mesh=self._mesh,
+                           in_specs=tuple(rows for _ in app.input_names),
+                           out_specs=tuple(rows for _ in app.output_names),
+                           check_vma=False)
+            batch_row = NamedSharding(self._mesh, rows)
             kwargs["in_shardings"] = tuple(
                 batch_row for _ in app.input_names)
             kwargs["out_shardings"] = tuple(
                 batch_row for _ in app.output_names)
-        return jax.jit(jax.vmap(app.fn), **kwargs)
+        return jax.jit(fn, **kwargs)
 
     def _call(self, app: CompiledApp, width: int,
               args: Sequence[np.ndarray]) -> Any:

@@ -142,7 +142,7 @@ def _tuning_context(spec: TPUSpec, strict: bool, canonicalize: bool,
 
 def default_measure(graph, backend, config: ScheduleConfig, *,
                     spec: TPUSpec | None = None, reps: int = 3,
-                    interpret: bool = True,
+                    interpret: bool | None = None,
                     seed: int = 0, strict: bool = False,
                     canonicalize: bool = True, passes=None) -> float:
     """Lower ``graph`` under ``config`` and time it on the live backend.
@@ -157,6 +157,7 @@ def default_measure(graph, backend, config: ScheduleConfig, *,
     from repro.backends import resolve
     from repro.core.compiler import compile_graph
     be = resolve(backend)
+    interpret = be.resolve_interpret(interpret)
     app = compile_graph(graph, be, tune=config, spec=spec or be.spec,
                         interpret=interpret, strict=strict,
                         canonicalize=canonicalize, passes=passes)
@@ -206,7 +207,7 @@ def tune_graph(graph, backend="pallas", *,
                device_kind: str | None = None, top_k: int = 3,
                max_trials: int = 12, reps: int = 3,
                measure: Callable[[ScheduleConfig], float] | None = None,
-               interpret: bool = True, seed: int = 0,
+               interpret: bool | None = None, seed: int = 0,
                strict: bool = False, canonicalize: bool = True,
                passes=None,
                max_tile_candidates: Sequence[tuple[int, int]] = (
@@ -253,6 +254,7 @@ def tune_graph(graph, backend="pallas", *,
     from repro.backends import resolve_calibrated
     be = resolve_calibrated(backend, calibrate)
     be.require("tuning")
+    interpret = be.resolve_interpret(interpret)
     spec = spec or be.spec
     # pruning is gated on evidence: only a spec that went through the
     # calibration fit (carries fitted per-kind ii multipliers) may veto
@@ -406,7 +408,7 @@ def tune_graph(graph, backend="pallas", *,
 def resolve_tuning(graph, backend, *, tune: Any,
                    spec: TPUSpec | None = None,
                    cache: TuningCache | None = None,
-                   interpret: bool = True,
+                   interpret: bool | None = None,
                    **tune_kwargs: Any) -> tuple[ScheduleConfig, str,
                                                 list[str]] | None:
     """Normalize a ``tune=`` argument into ``(config, source, notes)``.

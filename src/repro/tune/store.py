@@ -189,19 +189,15 @@ def default_cache_root() -> str:
 
 
 def detect_device_kind() -> str:
-    """Best-effort hardware identity for the tuning key.
+    """Hardware identity for the tuning key.
 
     A schedule measured on one device kind must not be served on
     another — the whole point of measuring — so the key carries
-    ``jax.devices()[0].device_kind`` (falling back to the platform
-    name, then ``"unknown"`` when JAX is unavailable).
+    ``jax.devices()[0].device_kind``.  A JAX that cannot reach its
+    devices raises rather than keying winners on a made-up kind.
     """
-    try:
-        import jax
-        dev = jax.devices()[0]
-        return getattr(dev, "device_kind", None) or dev.platform
-    except Exception:  # pragma: no cover - no backend at all
-        return "unknown"
+    import jax
+    return jax.devices()[0].device_kind
 
 
 class TuningCache:

@@ -128,6 +128,16 @@ def test_interpret_resolution_matches_seed_defaults_on_cpu():
         assert PALLAS.resolve_interpret(None) is True
 
 
+def test_interpreting_where_kernels_compile_natively_raises():
+    here = dataclasses.replace(PALLAS, name="native_here",
+                               native_platforms=(current_platform(),))
+    assert here.resolve_interpret(None) is False
+    assert here.resolve_interpret(False) is False
+    with pytest.raises(UnsupportedBackendError) as ei:
+        here.resolve_interpret(True)
+    assert ei.value.missing == ("compiled",)
+
+
 def test_donation_policy_matches_old_microbatcher_probe():
     for name in SEED_BACKENDS:
         be = resolve(name)

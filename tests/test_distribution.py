@@ -11,6 +11,7 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import sys
 sys.path.insert(0, "src")
 import jax, jax.numpy as jnp, numpy as np
+from repro.launch.mesh import make_mesh
 """
 
 
@@ -75,7 +76,7 @@ step1 = jax.jit(S.make_train_step(cfg, opt))
 s1, m1 = step1(jax.tree.map(jnp.copy, state), batch)
 
 # sharded 2x4
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 sh = S.train_state_shardings(cfg, mesh)
 from repro.models.config import ShapeConfig
 shp = ShapeConfig("t", 16, 8, "train")
@@ -132,12 +133,12 @@ from repro.checkpoint.checkpointer import save_pytree, restore_pytree
 cfg = get_smoke("granite_3_2b")
 params = M.init(cfg, jax.random.PRNGKey(1))
 state = {{"params": params, "opt": adamw_init(params)}}
-mesh1 = jax.make_mesh((2, 4), ("data", "model"))
+mesh1 = make_mesh((2, 4), ("data", "model"))
 sh1 = S.train_state_shardings(cfg, mesh1)
 state = jax.device_put(state, sh1)
 save_pytree(state, r"{tmp_path}", 3)
 
-mesh2 = jax.make_mesh((4, 2), ("data", "model"))
+mesh2 = make_mesh((4, 2), ("data", "model"))
 sh2 = S.train_state_shardings(cfg, mesh2)
 like = jax.eval_shape(lambda: state)
 restored = restore_pytree(like, r"{tmp_path}", 3, shardings=sh2)
@@ -164,7 +165,7 @@ data = SyntheticLM(vocab_size=cfg.vocab_size, seq_len=16, global_batch=8)
 batch = {k: jnp.asarray(v) for k, v in data.batch(0).items()}
 params = M.init(cfg, jax.random.PRNGKey(0))
 state = {"params": params, "opt": adamw_init(params), "ef": ef_init(params)}
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 step = jax.jit(S.make_train_step(cfg, opt, mesh=mesh, compress_grads=True))
 s, m = step(state, batch)
 assert np.isfinite(float(m["loss"]))

@@ -54,6 +54,19 @@ def test_compiled_app_runs_and_reports():
     assert "launch kernel[0]" in app.host_program()
 
 
+@pytest.mark.parametrize("halo", [(0, 0), (1, 1), (2, 3), (5, 130)])
+def test_input_block_is_tile_aligned_and_covers_the_halo_window(halo):
+    """Mosaic takes only whole (SUBLANE, LANE) input blocks; the block
+    is the halo-expanded window rounded up by less than one tile."""
+    from repro.core.fusion import _input_block
+    from repro.core.vectorize import LANE, SUBLANE
+    th, tw = 64, 256
+    bh, bw = _input_block((th, tw), halo)
+    assert bh % SUBLANE == 0 and bw % LANE == 0
+    assert th + 2 * halo[0] <= bh < th + 2 * halo[0] + SUBLANE
+    assert tw + 2 * halo[1] <= bw < tw + 2 * halo[1] + LANE
+
+
 def test_vector_factor_changes_tile():
     from repro.core import choose_tile
     g = APPS["gaussian_blur"][0](256, 1024)

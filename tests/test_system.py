@@ -96,3 +96,38 @@ def test_dryrun_skip_rule():
     assert skip_reason(get_config("mamba2-2.7b"), SHAPES["long_500k"]) is None
     assert skip_reason(get_config("zamba2-1.2b"), SHAPES["long_500k"]) is None
     assert skip_reason(get_config("qwen1.5-32b"), SHAPES["train_4k"]) is None
+
+
+@pytest.mark.parametrize("from_env", [True, False])
+def test_compile_cache_directory(from_env, tmp_path, monkeypatch):
+    """$JAX_COMPILATION_CACHE_DIR wins and nothing else is set; without
+    it the cache is the fixed .jax_cache/ at the checkout's root."""
+    import pathlib
+    from repro.launch import compile_cache
+    updates = {}
+    monkeypatch.setattr(jax.config, "update",
+                        lambda k, v: updates.__setitem__(k, v))
+    if from_env:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert compile_cache.use_compile_cache() == str(tmp_path)
+        assert "jax_compilation_cache_dir" not in updates
+    else:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        path = compile_cache.use_compile_cache()
+        root = pathlib.Path(__file__).resolve().parent.parent
+        assert pathlib.Path(path) == root / ".jax_cache"
+        assert updates["jax_compilation_cache_dir"] == path
+
+
+def test_benchmark_driver_counts_failed_modules(tmp_path, monkeypatch):
+    """A module that raises is reported and becomes a non-zero exit."""
+    import importlib.util
+    import pathlib
+    path = pathlib.Path(__file__).resolve().parent.parent / "benchmarks" / "run.py"
+    spec = importlib.util.spec_from_file_location("bench_driver", path)
+    driver = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(driver)
+    monkeypatch.chdir(tmp_path)
+    monkeypatch.setattr(driver, "MODULES", ["benchmarks.no_such_module"])
+    assert driver.main() == 1
+    assert (tmp_path / "experiments" / "bench_results.json").exists()
