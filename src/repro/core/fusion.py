@@ -105,7 +105,8 @@ def lower_group_pallas(group: FusionGroup, spec: TPUSpec, *,
     ``(SUBLANE, LANE)`` tiles, the host pad supplies the extra rows and
     columns, and the kernel crops the window back before the first
     stage.  ``spec.vmem_bytes`` is both the budget the tile picker fits
-    into and the scoped-VMEM limit handed to Mosaic.
+    into and the scoped-VMEM limit handed to Mosaic.  The kernel is
+    named after the group (``<app>_g<k>``).
     """
     if group.is_trivial:
         raise GraphError("cannot pallas-lower a custom/reduce group")
@@ -132,7 +133,7 @@ def lower_group_pallas(group: FusionGroup, spec: TPUSpec, *,
 
     call = pl.pallas_call(
         kernel, grid=grid, in_specs=in_specs, out_specs=out_specs,
-        out_shape=out_shapes, interpret=interpret,
+        out_shape=out_shapes, interpret=interpret, name=group.name,
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=int(spec.vmem_bytes)))
 

@@ -27,6 +27,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core.graph import DataflowGraph
 from repro.core.schedule import Schedule
+from repro.obs.tracer import get_tracer, program_span
 
 __all__ = ["CompiledApp", "LaunchHandle", "build_host_app"]
 
@@ -48,8 +49,11 @@ class LaunchHandle:
                    if hasattr(o, "is_ready"))
 
     def result(self) -> dict[str, Any]:
-        """Block until the computation finishes; return the outputs."""
-        jax.block_until_ready(self.outputs)
+        """Block until the computation finishes; return the outputs.
+
+        The wait is the ``app.wait`` program span."""
+        with program_span("app.wait", get_tracer()):
+            jax.block_until_ready(self.outputs)
         return self.outputs
 
 
@@ -93,10 +97,12 @@ class CompiledApp:
         keeps queuing.  The serving engine
         (:class:`repro.runtime.engine.StreamEngine`) builds its
         double-buffered pipeline on exactly this: launch item k+1
-        before blocking on item k.
+        before blocking on item k.  The dispatch is the ``app.launch``
+        program span (no args: it runs once a frame).
         """
         args = [inputs[n] for n in self.input_names]
-        outs = self.fn(*args)
+        with program_span("app.launch", get_tracer()):
+            outs = self.fn(*args)
         return LaunchHandle(dict(zip(self.output_names, outs)))
 
     def signature(self) -> str:

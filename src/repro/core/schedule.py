@@ -29,6 +29,7 @@ scheduler
 from __future__ import annotations
 
 import dataclasses
+import re
 from typing import Sequence
 
 import numpy as np
@@ -70,6 +71,9 @@ class FusionGroup:
     #: autotuner, fresh / from the TuningCache / an explicit
     #: ScheduleConfig).  Rendered by :meth:`Schedule.describe`.
     tile_source: str = "model"
+    #: the kernel's name, ``<app>_g<k>`` for the k-th group of the
+    #: schedule (set by :func:`build_schedule`)
+    name: str | None = None
 
     @property
     def is_trivial(self) -> bool:
@@ -225,6 +229,9 @@ def build_schedule(graph: DataflowGraph, n_bundles: int = 4, *,
                                                  vector_factor,
                                                  backend=backend)
         sp.set(groups=len(groups))
+    app = re.sub(r"\W", "_", graph.name)
+    for k, g in enumerate(groups):
+        g.name = f"{app}_g{k}"
     diagnostics.extend(fusion_diags)
     diagnostics.extend(_select_tiles(groups, spec, vector_factor,
                                      group_vf=group_vector_factors,

@@ -14,9 +14,10 @@ with hysteresis, :mod:`~repro.obs.drift` persists the
 :mod:`~repro.obs.sentinel` watches those pairs and triggers
 recalibration when the fitted constants go stale.
 
-This package imports only the standard library and numpy at module
-load — every repro layer can depend on it without cycles (the
-sentinel pulls in :mod:`repro.tune` lazily, at use).
+This package imports only the standard library, numpy and
+``jax.profiler`` at module load — every repro layer can depend on it
+without cycles (the sentinel pulls in :mod:`repro.tune` lazily, at
+use).
 """
 from repro.obs.drift import (DRIFT_ENV, DriftLog, DriftRow,
                              default_drift_path, drift_report,
@@ -32,12 +33,12 @@ from repro.obs.health import SLO, STATES, HealthMonitor
 from repro.obs.metrics import Counter, Gauge, Histogram, MetricsRegistry
 from repro.obs.sentinel import DriftSentinel, SentinelPolicy
 from repro.obs.tracer import (TRACE_ENV, Event, Tracer, get_tracer,
-                              install, maybe_span, resolve_tracer,
-                              uninstall)
+                              install, maybe_span, program_span,
+                              resolve_tracer, uninstall)
 
 __all__ = [
     "Event", "Tracer", "install", "uninstall", "get_tracer",
-    "resolve_tracer", "maybe_span", "TRACE_ENV",
+    "resolve_tracer", "maybe_span", "program_span", "TRACE_ENV",
     "Counter", "Gauge", "Histogram", "MetricsRegistry",
     "to_chrome_events", "export_chrome_trace", "load_chrome_trace",
     "validate_chrome_trace",

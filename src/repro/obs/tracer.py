@@ -27,6 +27,12 @@ Three recording idioms, matching how the stack is instrumented:
   submit→complete timeline at retirement, from timestamps captured on
   the hot path — the recording itself never sits on that path.
 
+The serving hot path (engine worker, batcher, ``CompiledApp.launch``)
+uses one more idiom, :func:`program_span`: a span that always enters
+``jax.profiler.TraceAnnotation`` — so it lands in a JAX profiler trace
+on the same clock as the device's operations — and that this tracer
+records too when one is given.
+
 **Cost discipline.**  A disabled tracer (``enabled=False``) returns a
 shared no-op context from ``span`` and early-outs of every record
 method — a couple of attribute loads, no allocation, no lock.  Code on
@@ -39,8 +45,9 @@ benchmark flags and the ``REPRO_TRACE`` environment variable:
 ``REPRO_TRACE`` is set to a path, the global tracer auto-exports there
 at interpreter exit.
 
-This module imports nothing from the rest of the repo — any layer
-(core, runtime, tune) can depend on it without cycles.
+This module imports nothing from the rest of the repo (only
+``jax.profiler`` beside the standard library) — any layer (core,
+runtime, tune) can depend on it without cycles.
 """
 from __future__ import annotations
 
@@ -50,8 +57,10 @@ import time
 from collections import deque
 from typing import Any
 
+from jax.profiler import TraceAnnotation
+
 __all__ = ["Event", "Tracer", "install", "uninstall", "get_tracer",
-           "resolve_tracer", "maybe_span", "TRACE_ENV"]
+           "resolve_tracer", "maybe_span", "program_span", "TRACE_ENV"]
 
 #: environment variable that enables the process-global tracer; set it
 #: to ``1`` to record, or to a ``.json`` path to also auto-export a
@@ -404,3 +413,45 @@ def maybe_span(tracer: Tracer | None, name: str, cat: str = "span",
     if tracer is None:
         return _NOOP
     return tracer.span(name, cat, **attrs)
+
+
+class _ProgramSpan:
+    """A profiler annotation that a flight recorder also records."""
+
+    __slots__ = ("_ann", "_tracer", "_name", "_args", "_t0")
+
+    def __init__(self, name: str, tracer: Tracer, args: dict[str, Any]):
+        self._ann = TraceAnnotation(name, **args)
+        self._tracer = tracer
+        self._name = name
+        self._args = args
+
+    def __enter__(self) -> "_ProgramSpan":
+        self._ann.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc: Any) -> None:
+        t1 = time.perf_counter()
+        self._ann.__exit__(*exc)
+        self._tracer.complete(self._name, self._t0, t1 - self._t0,
+                              cat=self._name.split(".", 1)[0],
+                              **self._args)
+
+
+def program_span(name: str, tracer: Tracer | None = None, **args: Any):
+    """A span of the program's own work, as a ``with`` context.
+
+    It always enters ``jax.profiler.TraceAnnotation(name, **args)``:
+    inside a profiler session the span lands on the host thread's line
+    of the trace, on the device operations' clock; with no session it
+    records nothing and costs about a microsecond (less without
+    ``args``).  With a ``tracer``, the flight recorder also records it
+    as a complete (``X``) span of the same name and args, category the
+    name's first dotted part (``engine.wait`` -> ``engine``).  Without
+    one, the span reads no clock of its own.  Args are work counts
+    (batch id, width, bytes): plain numbers, never device arrays.
+    """
+    if tracer is None:
+        return TraceAnnotation(name, **args)
+    return _ProgramSpan(name, tracer, args)

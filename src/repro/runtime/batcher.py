@@ -62,6 +62,8 @@ class ContinuousBatcher:
         # slot occupancy / admission queue / retirement: the machinery
         # shared with the dataflow StreamEngine (see runtime/slots.py)
         self.pool: SlotPool = SlotPool(n_slots)
+        #: retired requests, in retirement order
+        self.finished: list[Request] = []
         self._decode = jax.jit(self._decode_step)
 
     # ------------------------------------------------------------------
@@ -79,10 +81,6 @@ class ContinuousBatcher:
     @property
     def slot_req(self) -> list[Request | None]:
         return self.pool.slots
-
-    @property
-    def finished(self) -> list[Request]:
-        return self.pool.finished
 
     # ------------------------------------------------------------------
     def _admit(self) -> None:
@@ -168,7 +166,7 @@ class ContinuousBatcher:
         # machinery shared with StreamEngine's in-flight launch pool),
         # then the next _admit() backfills them without a drain barrier
         for slot in self.pool.ready(lambda r: r.done):
-            self.pool.retire(slot)
+            self.finished.append(self.pool.retire(slot))
             self.lengths[slot] = 0
         return produced
 

@@ -46,7 +46,7 @@ from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core.host import CompiledApp
-from repro.obs.tracer import resolve_tracer
+from repro.obs.tracer import program_span, resolve_tracer
 
 __all__ = ["MicroBatcher"]
 
@@ -114,13 +114,23 @@ class MicroBatcher:
         self._staging_clock: dict[tuple[str, int], int] = {}
         #: width -> number of launches that used that bucket
         self.bucket_launches: dict[int, int] = {}
-        #: flight recorder for per-bucket stack/launch spans (None =
-        #: untraced; ``False`` opts out even of the global tracer)
+        #: launches begun so far; launch k carries ``batch=k`` on its
+        #: ``batch.stack`` / ``batch.launch`` spans
+        self.launches = 0
+        #: flight recorder that also records the stack/launch spans
+        #: (None = profiler annotations only; ``False`` opts out even
+        #: of the global tracer)
         self.tracer = resolve_tracer(trace) if trace is not False else None
 
     # ------------------------------------------------------------------
     # bucketed pad widths
     # ------------------------------------------------------------------
+    def width(self, n: int, pad_to: int | None = None) -> int:
+        """Staged width of an ``n``-request batch: its :meth:`bucket`,
+        or ``pad_to`` if wider, rounded up to a replica multiple."""
+        width = max(pad_to or 0, self.bucket(n), n)
+        return -(-width // self.replicas) * self.replicas
+
     def bucket(self, n: int) -> int:
         """Padded width for an ``n``-request batch.
 
@@ -249,9 +259,7 @@ class MicroBatcher:
             raise ValueError(
                 "cannot stack an empty request batch (engine shutdown "
                 "race?); callers must skip empty batches")
-        width = max(pad_to or 0, self.bucket(len(requests)), len(requests))
-        width = -(-width // self.replicas) * self.replicas
-        args = self._staging_bufs(app, width)
+        args = self._staging_bufs(app, self.width(len(requests), pad_to))
         for j, ch in enumerate(app.graph.graph_inputs):
             buf = args[j]
             name = ch.name
@@ -283,7 +291,9 @@ class MicroBatcher:
         ``len(requests)`` are padding and must be ignored by the
         caller.  ``timings``, when given, receives the host-side
         ``stack`` (staging-copy) and ``launch`` (dispatch) phase
-        durations in seconds.
+        durations in seconds.  Both phases are program spans
+        (``batch.stack``, ``batch.launch``) carrying this launch's
+        ``batch`` id (:attr:`launches` before the call) and ``width``.
         """
         if len(requests) > self.max_batch:
             raise ValueError(
@@ -292,24 +302,21 @@ class MicroBatcher:
             raise ValueError(
                 "cannot stack an empty request batch (engine shutdown "
                 "race?); callers must skip empty batches")
+        seq = self.launches
+        self.launches += 1
+        width = self.width(len(requests), pad_to)
         t0 = time.perf_counter()
-        args = self.stack(app, requests, pad_to=pad_to,
-                          check_shapes=check_shapes)
-        width = args[0].shape[0] if args else len(requests)
+        with program_span("batch.stack", self.tracer, batch=seq,
+                          width=width, rows=len(requests)):
+            args = self.stack(app, requests, pad_to=pad_to,
+                              check_shapes=check_shapes)
         t1 = time.perf_counter()
-        outs = self._call(app, width, args)
+        with program_span("batch.launch", self.tracer, batch=seq,
+                          width=width):
+            outs = self._call(app, width, args)
         t2 = time.perf_counter()
         self.bucket_launches[width] = self.bucket_launches.get(width, 0) + 1
         if timings is not None:
             timings["stack"] = t1 - t0
             timings["launch"] = t2 - t1
-        if self.tracer is not None:
-            # retroactive complete spans from the stamps above — the
-            # recording itself adds nothing between stack and dispatch
-            self.tracer.complete("batch.stack", t0, t1 - t0,
-                                 cat="batcher", app=app.graph.name,
-                                 width=width, rows=len(requests))
-            self.tracer.complete("batch.launch", t1, t2 - t1,
-                                 cat="batcher", app=app.graph.name,
-                                 width=width)
         return dict(zip(app.output_names, outs))

@@ -44,10 +44,17 @@ paper's dataflow machines applied to the host side.
   capacity.
 - **telemetry** — queue depth, p50/p99 latency, throughput, shed and
   cancel counts, and a per-phase breakdown of the hot path
-  (queue-wait / form / stack / launch / readback), reported
+  (queue-wait / form / stack / launch / readback, the last split into
+  wait for the device and copy to the host), reported
   side-by-side with the Fig. 1
   :func:`~repro.core.simulate.analytic_latency` prediction
   (:meth:`StreamEngine.report`).
+- **program spans** — each of the worker's phases is a
+  :func:`~repro.obs.tracer.program_span` (``batch.stack``,
+  ``batch.launch``, ``engine.wait``, ``engine.copy``, ``engine.park``;
+  ``engine.submit`` on the client's thread), so a JAX profiler trace
+  shows them on the device's clock; the spans of one batch share its
+  ``batch`` id and ``width``.
 
 See ``docs/serving.md`` for the operator-facing tour of all of this.
 """
@@ -58,6 +65,7 @@ import time
 from collections import deque
 from typing import Any, Mapping
 
+import jax
 import numpy as np
 
 from repro.core.graph import DataflowGraph
@@ -65,7 +73,7 @@ from repro.core.host import CompiledApp
 from repro.core.vectorize import modeled_schedule_time, schedule_features
 from repro.obs.drift import resolve_drift
 from repro.obs.health import SLO, HealthMonitor
-from repro.obs.tracer import resolve_tracer
+from repro.obs.tracer import program_span, resolve_tracer
 from repro.runtime.batching import MicroBatcher
 from repro.runtime.cache import CompileCache
 from repro.runtime.slots import SlotPool
@@ -363,8 +371,15 @@ class StreamEngine:
         compiled app.  When the app's bounded queue is full, ``submit``
         blocks (bounded by ``timeout``) or, with ``block=False``,
         raises :class:`QueueFullError` — admission control sheds load
-        for THIS app only; other apps keep their own headroom.
+        for THIS app only; other apps keep their own headroom.  The
+        call is the ``engine.submit`` program span.
         """
+        with program_span("engine.submit", self.tracer):
+            return self._submit(graph, inputs, block, timeout)
+
+    def _submit(self, graph: DataflowGraph | CompiledApp,
+                inputs: Mapping[str, Any], block: bool,
+                timeout: float | None) -> StreamRequest:
         if self._stop.is_set():
             raise RuntimeError("engine is closed")
         if isinstance(graph, CompiledApp):
@@ -798,6 +813,7 @@ class StreamEngine:
                 r._fail(e)
             return
         t_disp = time.perf_counter()
+        seq = self._batcher.launches - 1     # this launch's batch id
         self._form_obs.update(timings)
         with self._obs_lock:
             self._obs.append((t_disp, len(batch), self._form_obs,
@@ -810,7 +826,7 @@ class StreamEngine:
         t_s0 = t_s1 - timings.get("stack", 0.0)
         if not self._pool.free_slots():
             self._retire(self._pool.oldest())     # rotate: block on oldest
-        self._pool.submit((batch, outs, t_disp, (t_s0, t_s1)))
+        self._pool.submit((batch, outs, t_disp, (t_s0, t_s1), seq))
         self._pool.admit()
 
     def _reap(self) -> None:
@@ -832,11 +848,23 @@ class StreamEngine:
             self._retire(slot)
 
     def _retire(self, slot: int | None) -> None:
+        """Wait for one batch's outputs, copy them to the host, and
+        complete its requests.  Past this call nothing of the batch
+        stays on the device: the pool hands the slot's item back and
+        only host copies reach the requests."""
         if slot is None:
             return
-        batch, outs, t_disp, stage_ts = self._pool.retire(slot)
+        batch, outs, t_disp, stage_ts, seq = self._pool.retire(slot)
+        width = next(iter(outs.values())).shape[0] if outs else len(batch)
+        nbytes = sum(v.nbytes for v in outs.values())
         t0 = time.perf_counter()
-        host = {k: np.asarray(v) for k, v in outs.items()}  # blocks here
+        with program_span("engine.wait", self.tracer, batch=seq,
+                          width=width):
+            jax.block_until_ready(outs)
+        t_ready = time.perf_counter()
+        with program_span("engine.copy", self.tracer, batch=seq,
+                          width=width, bytes=nbytes):
+            host = {k: np.asarray(v) for k, v in outs.items()}
         now = time.perf_counter()
         # claim completions quietly, record them, THEN wake waiters —
         # a caller that wakes from result() and immediately reads
@@ -859,7 +887,9 @@ class StreamEngine:
                               _SERVICE_ALPHA * svc
                               + (1.0 - _SERVICE_ALPHA) * prev)
         with self._obs_lock:
-            self._obs.append((now, None, {"readback": now - t0},
+            self._obs.append((now, None, {"wait": t_ready - t0,
+                                          "copy": now - t_ready,
+                                          "readback": now - t0},
                               done, svc))
             backlog = len(self._obs)
         if done:
@@ -869,29 +899,32 @@ class StreamEngine:
         # trace/drift emission AFTER waking waiters: it is retroactive
         # bookkeeping reconstructed from stamps, never waiter latency
         if self.tracer is not None or self.drift is not None:
-            self._record_batch(batch, winners, host, t_disp, stage_ts,
-                               t0, now, svc)
+            self._record_batch(batch, winners, width, t_disp, stage_ts,
+                               (t0, t_ready), now, svc)
         if backlog >= 64:
             self._flush_obs()
 
     def _record_batch(self, batch: list[StreamRequest],
-                      winners: list[StreamRequest],
-                      host: dict[str, np.ndarray], t_disp: float,
-                      stage_ts: tuple[float, float], t0: float,
-                      now: float, svc: float) -> None:
+                      winners: list[StreamRequest], width: int,
+                      t_disp: float, stage_ts: tuple[float, float],
+                      wait_ts: tuple[float, float], now: float,
+                      svc: float) -> None:
         """Emit one retired batch's trace timelines and drift row.
 
         Runs on the worker thread at retirement, entirely from
         timestamps captured earlier — nothing here sat on the
         submit→launch path.  Each *winning* request (cancelled ones
         produce no timeline) gets a contiguous async phase chain
-        ``queue_wait → form → stack → launch → execute → readback``
-        tiling exactly [t_submit, complete] under its trace id.
+        ``queue_wait → form → stack → launch → inflight → wait → copy``
+        tiling exactly [t_submit, complete] under its trace id:
+        ``inflight`` runs from dispatch until the worker turns to
+        retire the batch, ``wait`` until its outputs are ready on the
+        device, ``copy`` until they are on the host.
         """
         app = batch[0].app
         sig = app.signature()
-        width = next(iter(host.values())).shape[0] if host else len(batch)
         t_s0, t_s1 = stage_ts
+        t0, t_ready = wait_ts
         tr = self.tracer
         if tr is not None:
             name = app.graph.name
@@ -908,8 +941,9 @@ class StreamEngine:
                 tr.async_span("form", aid, tt, t_s0, cat="request")
                 tr.async_span("stack", aid, t_s0, t_s1, cat="request")
                 tr.async_span("launch", aid, t_s1, t_disp, cat="request")
-                tr.async_span("execute", aid, t_disp, t0, cat="request")
-                tr.async_span("readback", aid, t0, now, cat="request")
+                tr.async_span("inflight", aid, t_disp, t0, cat="request")
+                tr.async_span("wait", aid, t0, t_ready, cat="request")
+                tr.async_span("copy", aid, t_ready, now, cat="request")
                 tr.async_event("request", "e", aid, ts=now, cat="request")
             tr.counter("engine.inflight", self._pool.active)
         if self.drift is not None:
@@ -937,8 +971,9 @@ class StreamEngine:
                 backend_key=self._backend_key, features=features)
 
     def _wait_for_work(self) -> None:
-        """Park until new work arrives or the formation deadline lands."""
-        with self._cond:
+        """Park until new work arrives or the formation deadline lands
+        (the ``engine.park`` program span)."""
+        with program_span("engine.park", self.tracer), self._cond:
             if self._stop.is_set() and self._pending:
                 return
             self._cond.wait(min(self._form_wait, self._poll))

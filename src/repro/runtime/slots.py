@@ -11,7 +11,10 @@ out of slots as they complete.
   ``n_slots=2`` is exactly the double buffering of a depth-2 FIFO).
 
 :class:`SlotPool` is that shared core: bounded occupancy, FIFO
-admission, admission-order retirement bookkeeping.
+admission, admission-order retirement bookkeeping.  A retired item is
+handed back and the pool keeps no reference to it: the engine's items
+hold a batch's outputs on the device, so whatever outlives retirement
+is the caller's choice.
 """
 from __future__ import annotations
 
@@ -30,7 +33,6 @@ class SlotPool:
         self.n_slots = n_slots
         self.slots: list[Any | None] = [None] * n_slots
         self.queue: deque[Any] = deque()
-        self.finished: list[Any] = []
         self._order: deque[int] = deque()   # admission order of busy slots
 
     # -- admission -----------------------------------------------------
@@ -81,11 +83,10 @@ class SlotPool:
 
     # -- retirement ----------------------------------------------------
     def retire(self, slot: int) -> Any:
-        """Free ``slot``; its item moves to ``finished`` and is returned."""
+        """Free ``slot`` and return its item."""
         item = self.slots[slot]
         if item is None:
             raise ValueError(f"slot {slot} is not occupied")
         self.slots[slot] = None
         self._order.remove(slot)
-        self.finished.append(item)
         return item

@@ -37,8 +37,11 @@ __all__ = ["PHASES", "Telemetry", "modeled_latency"]
 #: the submit→complete hot path, phase by phase: time spent queued
 #: before being taken into a batch, waiting for the batch to form,
 #: staging rows into the pinned batch buffers, dispatching the kernel,
-#: and forcing outputs back to host memory
-PHASES = ("queue_wait", "form", "stack", "launch", "readback")
+#: and forcing outputs back to host memory (``readback``), which is
+#: ``wait`` (until the outputs are ready on the device) plus ``copy``
+#: (device to host)
+PHASES = ("queue_wait", "form", "stack", "launch", "wait", "copy",
+          "readback")
 
 #: reservoir capacity for each sample stream (latency, depths, ...)
 _MAX_SAMPLES = 100_000

@@ -61,3 +61,20 @@ def test_batcher_overlaps_requests():
     for r in finished:
         assert len(r.tokens) == r.max_new_tokens
         assert all(0 <= t < cfg.vocab_size for t in r.tokens)
+
+
+def test_finished_lists_retired_requests_in_retirement_order():
+    """The batcher, not the slot pool it shares with StreamEngine, keeps
+    what it retired: shorter budgets retire first and free their slot."""
+    cfg = dataclasses.replace(get_smoke("mamba2_2p7b"),
+                              capacity_factor=8.0)
+    params = M.init(cfg, jax.random.PRNGKey(2))
+    batcher = ContinuousBatcher(cfg, params, n_slots=2, max_len=48)
+    prompt = np.arange(4, dtype=np.int32)
+    for rid, budget in enumerate((4, 2, 2)):
+        batcher.submit(Request(rid=rid, prompt=prompt,
+                               max_new_tokens=budget))
+    finished = batcher.run_to_completion()
+    assert finished is batcher.finished
+    assert [r.rid for r in finished] == [1, 2, 0]
+    assert not hasattr(batcher.pool, "finished")

@@ -382,20 +382,21 @@ def test_engine_trace_single_id_contiguous_phases(tmp_path):
               and e.name != "request"]
     phases.sort(key=lambda e: e.ts)
     assert [e.name for e in phases] == ["queue_wait", "form", "stack",
-                                       "launch", "execute", "readback"]
+                                       "launch", "inflight", "wait",
+                                       "copy"]
     # phase spans tile submit→complete with no gaps: each 'b' at the
     # previous phase's 'e'
     evs = [e for e in tr.events() if e.cat == "request" and e.aid == aid]
     b_ts = {e.name: e.ts for e in evs if e.ph == "b"}
     e_ts = {e.name: e.ts for e in evs if e.ph == "e"}
-    chain = ["queue_wait", "form", "stack", "launch", "execute",
-             "readback"]
+    chain = ["queue_wait", "form", "stack", "launch", "inflight", "wait",
+             "copy"]
     assert b_ts["queue_wait"] == pytest.approx(b_ts["request"], abs=1e-9)
     for prev, nxt in zip(chain, chain[1:]):
         assert e_ts[prev] == pytest.approx(b_ts[nxt], abs=1e-9)
-    assert e_ts["readback"] == pytest.approx(e_ts["request"], abs=1e-9)
+    assert e_ts["copy"] == pytest.approx(e_ts["request"], abs=1e-9)
     # the batcher's stack/launch X spans rode the same tracer
-    assert {e.name for e in tr.events() if e.cat == "batcher"} == {
+    assert {e.name for e in tr.events() if e.cat == "batch"} == {
         "batch.stack", "batch.launch"}
     validate_chrome_trace(export_chrome_trace(tr, str(tmp_path / "e.json")))
 

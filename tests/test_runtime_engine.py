@@ -235,9 +235,10 @@ def test_slot_pool_fifo_admission_and_retirement():
     assert pool.admit() == [(oldest, "c")]
     # retirement follows admission order, not slot index order
     assert pool.slots[pool.oldest()] == "b"
-    pool.retire(pool.oldest())
-    pool.retire(pool.oldest())
-    assert pool.finished == ["a", "b", "c"]
+    retired = [pool.retire(pool.oldest()), pool.retire(pool.oldest())]
+    assert retired == ["b", "c"]
+    # the pool hands items back and keeps none of them
+    assert not hasattr(pool, "finished")
     with pytest.raises(ValueError):
         pool.retire(0)                       # empty slot
     assert pool.busy                         # "d" still queued
