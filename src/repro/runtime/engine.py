@@ -326,6 +326,8 @@ class StreamEngine:
         self._rr: deque[str] = deque()              # round-robin order
         self._pending = 0                           # queued across apps
         self._pool = SlotPool(inflight)
+        #: shards a retired batch's outputs came back in -> batches
+        self._readback_shards: dict[int, int] = {}
         # staging_depth must EXCEED inflight: a batch is staged before
         # the oldest slot is retired, so `inflight` launches can be
         # unforced while the next one stages — and on CPU a jit call
@@ -486,6 +488,7 @@ class StreamEngine:
                              "shed": aq.shed}
         out["apps"] = apps
         out["buckets"] = dict(self._batcher.bucket_launches)
+        out["readback_shards"] = dict(self._readback_shards)
         return out
 
     # ------------------------------------------------------------------
@@ -798,6 +801,10 @@ class StreamEngine:
         return batch
 
     def _dispatch(self, batch: list[StreamRequest]) -> None:
+        """Launch one batch and put it in flight.  Each output's copy
+        to the host starts here, queued behind the kernel on every
+        device that holds a shard of it, so it runs while the worker
+        stages the next batch and retires the previous one."""
         app = batch[0].app
         timings: dict[str, float] = {}
         try:
@@ -812,6 +819,12 @@ class StreamEngine:
             for r in batch:
                 r._fail(e)
             return
+        for v in outs.values():
+            # start each shard's copy, not the array's: an array on one
+            # device is another object than its shard's data, and
+            # _retire takes the copy the shard's data holds
+            for shard in v.addressable_shards:
+                shard.data.copy_to_host_async()
         t_disp = time.perf_counter()
         seq = self._batcher.launches - 1     # this launch's batch id
         self._form_obs.update(timings)
@@ -848,24 +861,40 @@ class StreamEngine:
             self._retire(slot)
 
     def _retire(self, slot: int | None) -> None:
-        """Wait for one batch's outputs, copy them to the host, and
-        complete its requests.  Past this call nothing of the batch
-        stays on the device: the pool hands the slot's item back and
-        only host copies reach the requests."""
+        """Wait for one batch's outputs, take their host copies shard by
+        shard, and complete its requests.
+
+        The copies were started at dispatch.  Each device's shard of an
+        output comes back as its own host array, and request ``i`` gets
+        row ``i - start`` of the shard whose rows ``[start, stop)`` hold
+        it: no batch-wide host array is built.  An output on one device
+        is one shard covering the whole batch.  Past this call nothing
+        of the batch stays on the device: the pool hands the slot's item
+        back and only host copies reach the requests."""
         if slot is None:
             return
         batch, outs, t_disp, stage_ts, seq = self._pool.retire(slot)
         width = next(iter(outs.values())).shape[0] if outs else len(batch)
         nbytes = sum(v.nbytes for v in outs.values())
+        shards = {k: v.addressable_shards for k, v in outs.items()}
+        n_shards = len(next(iter(shards.values()))) if shards else 0
         t0 = time.perf_counter()
         with program_span("engine.wait", self.tracer, batch=seq,
                           width=width):
             jax.block_until_ready(outs)
         t_ready = time.perf_counter()
+        rows: list[dict[str, np.ndarray]] = [{} for _ in batch]
         with program_span("engine.copy", self.tracer, batch=seq,
-                          width=width, bytes=nbytes):
-            host = {k: np.asarray(v) for k, v in outs.items()}
+                          width=width, bytes=nbytes, shards=n_shards):
+            for k, parts in shards.items():
+                for shard in parts:
+                    start, stop, _ = shard.index[0].indices(width)
+                    host = np.asarray(shard.data)
+                    for i in range(start, min(stop, len(batch))):
+                        rows[i][k] = host[i - start]
         now = time.perf_counter()
+        self._readback_shards[n_shards] = (
+            self._readback_shards.get(n_shards, 0) + 1)
         # claim completions quietly, record them, THEN wake waiters —
         # a caller that wakes from result() and immediately reads
         # report() must see its own completion.  Requests whose claim
@@ -873,9 +902,8 @@ class StreamEngine:
         done: list[float] = []
         winners: list[StreamRequest] = []
         wake: list[threading.Event] = []
-        for i, req in enumerate(batch):
-            won, event = req._finish_quiet(
-                {k: v[i] for k, v in host.items()})
+        for req, row in zip(batch, rows):
+            won, event = req._finish_quiet(row)
             if won:
                 done.append(now - req.t_submit)
                 winners.append(req)

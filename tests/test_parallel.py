@@ -309,3 +309,39 @@ mod = next(iter(rep["modeled"].values()))
 assert mod["replica_scaling_modeled"] > 1.0
 print("ok")
 """)
+
+
+@pytest.mark.parametrize("replicas", [4, 1])
+def test_engine_readback_hands_each_request_its_shard(replicas):
+    """Each request's result is a row of its own device's host copy:
+    with 4 replicas a 2-row shard of the 8-wide batch, never a
+    batch-wide array; with 1 the single shard is the whole batch."""
+    run_sub(f"""
+import gc
+from repro.runtime import StreamEngine
+replicas = {replicas}
+g = build_app("filter_chain", 32, 128)
+app = compile_graph(build_app("filter_chain", 32, 128), backend="xla")
+rng = np.random.default_rng(1)
+xs = [rng.normal(size=(32, 128)).astype(np.float32) for _ in range(13)]
+ref = [np.asarray(app(img=x)["out"]) for x in xs]
+eng = StreamEngine(backend="xla", max_batch=8, replicas=replicas)
+got = []
+for order in (list(range(8)), list(rng.permutation(13))):
+    # queued whole under the engine's re-entrant condition: 8 frames
+    # form one batch of 8, 13 form batches of 8 and 5 (width 8)
+    with eng._cond:
+        reqs = [(i, eng.submit(g, {{"img": xs[i]}})) for i in order]
+    got += [(i, r.result(timeout=300)["out"]) for i, r in reqs]
+rep = eng.report()
+eng.close()
+del eng
+gc.collect()
+assert rep["buckets"] == {{8: 3}}, rep["buckets"]
+assert rep["readback_shards"] == {{replicas: 3}}, rep["readback_shards"]
+for i, out in got:
+    assert np.array_equal(out, ref[i]), i
+    rows = None if out.base is None else out.base.shape[0]
+    assert rows == 8 // replicas, (i, rows)
+print("ok")
+""")

@@ -91,7 +91,9 @@ def _check_batches(spans):
         assert len({int(s[3]["width"]) for s in got}) == 1
         widths.append(int(got[0][3]["width"]))
         copy = got[-1][3]
+        assert {"batch", "width", "bytes", "shards"} <= set(copy)
         assert int(copy["bytes"]) == int(got[0][3]["width"]) * 8 * 128 * 4
+        assert int(copy["shards"]) == 1         # one device, one shard
     return sorted(widths)
 
 
@@ -125,6 +127,7 @@ def test_every_batch_width_has_one_wait_then_one_copy(sink, tmp_path, rng):
     assert phases["wait"]["count"] == phases["copy"]["count"] == len(WIDTHS)
     assert (phases["wait"]["mean_ms"] + phases["copy"]["mean_ms"]
             == pytest.approx(phases["readback"]["mean_ms"], rel=1e-6))
+    assert rep["readback_shards"] == {1: len(WIDTHS)}
 
 
 def test_app_launch_and_wait_spans_on_the_device_path(tmp_path):
