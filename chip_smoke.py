@@ -100,7 +100,7 @@ def one_chip() -> None:
         app = compile_graph(graph, backend="pallas")
         dt = time.perf_counter() - t0
         total_compile += dt
-        kernels = sum(1 for g in app.schedule.groups if not g.is_trivial)
+        kernels = sum(1 for g in app.schedule.groups if g.is_kernel)
         calls = app.compiled.as_text().count(
             'custom_call_target="tpu_custom_call"')
         log(f"{name}: compile_graph {dt:.2f} s (smoke run), "

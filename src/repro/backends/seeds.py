@@ -32,19 +32,20 @@ def _lower_xla(group, *, backend: Backend, spec: Any,
 
 
 def _lower_xla_staged(group, **kw) -> Callable:
-    # trivial (custom/reduce) groups are single opaque stages: there is
-    # nothing to stage *between*, and the plain composition is what the
+    # custom/reduce groups are single opaque stages: there is nothing to
+    # stage *between*, and the plain composition is what the
     # pre-registry chain ran for them on every backend
-    return _lower_xla(group, staged=not group.is_trivial, **kw)
+    return _lower_xla(group, staged=group.kind != "custom", **kw)
 
 
 def _lower_pallas(group, *, backend: Backend, spec: Any,
                   vector_factor: int | None, interpret: bool,
                   valid_rows: tuple[int, int] | None) -> Callable:
     from repro.core.fusion import lower_group_pallas, lower_group_xla
-    if group.is_trivial:
-        # custom/reduce singletons have no streaming tile structure;
-        # they run as host-composed jnp on every backend
+    if not group.is_kernel:
+        # custom/reduce singletons have no streaming tile structure and
+        # run as host-composed jnp on every backend; a group of splits
+        # only hands its input on (the readers share one HBM buffer)
         return lower_group_xla(group, staged=False, valid_rows=valid_rows)
     return lower_group_pallas(group, spec, interpret=interpret,
                               vector_factor=vector_factor,

@@ -42,7 +42,8 @@ __all__ = ["Backend", "UnsupportedBackendError", "STAGE_KINDS"]
 
 #: every stage kind a DataflowGraph can contain; a backend's
 #: capability set is validated against this vocabulary
-STAGE_KINDS = ("point", "pointN", "split", "stencil", "custom", "reduce")
+STAGE_KINDS = ("point", "pointN", "split", "stencil", "resample", "custom",
+               "reduce")
 
 #: non-stage capability flags a backend may declare
 FEATURE_CAPS = ("fused_streaming", "staged_hbm", "replication", "tuning")

@@ -1,5 +1,5 @@
-"""The paper's benchmark applications (Table I), as single-source
-traced programs.
+"""The paper's benchmark applications (Table I), and a multi-rate
+pyramid app, as single-source traced programs.
 
 Each builder is now exactly what the paper promises: a plain Python
 array function — operators for point math, :func:`fe.conv` /
@@ -175,6 +175,43 @@ def optical_flow_lk(h: int, w: int, eps: float = 1e-3) -> DataflowGraph:
                     name="optical_flow_lk")
 
 
+def pyramid_blend(h: int, w: int, levels: int = 4) -> DataflowGraph:
+    """Multi-band blending of two RGB images under one 8-bit mask
+    (Burt & Adelson, ACM TOG 2(4), 1983): per channel, blend the two
+    Laplacian pyramids under the mask's Gaussian pyramid and collapse.
+    PolyMage's "Pyramid Blending" benchmark runs it at 2048x2048, 4
+    levels.
+
+    The mask's pyramid is taken on its 8-bit levels and each level is
+    scaled to [0, 1] where it weighs the blend (``REDUCE`` is linear, so
+    this is ``REDUCE^l(M/255)`` up to rounding).  A scale ahead of a
+    correlation would be folded into its taps by XLA's simplifier
+    wherever the two share a fusion, and a kernel boundary keeps them
+    apart, so the backends would round differently."""
+    mix = lib.mask_blend(255.0)
+
+    def pyramid_blend_src(a_r, a_g, a_b, b_r, b_g, b_b, mask):
+        def gaussian(x):
+            pyr = [x]
+            for _ in range(levels - 1):
+                pyr.append(fe.pyr_down(pyr[-1]))
+            return pyr
+
+        gm = gaussian(mask)
+        out = {}
+        for c, a, b in (("r", a_r, b_r), ("g", a_g, b_g), ("b", a_b, b_b)):
+            ga, gb = gaussian(a), gaussian(b)
+            s = mix(gm[-1], ga[-1], gb[-1])
+            for lv in reversed(range(levels - 1)):
+                la = ga[lv] - fe.pyr_up(ga[lv + 1])
+                lb = gb[lv] - fe.pyr_up(gb[lv + 1])
+                s = mix(gm[lv], la, lb) + fe.pyr_up(s)
+            out[f"out_{c}"] = s
+        return out
+
+    return fe.trace(pyramid_blend_src, *[(h, w)] * 7, name="pyramid_blend")
+
+
 #: name -> (builder, table-I stage count, n_inputs)
 APPS: dict[str, tuple[Callable[..., DataflowGraph], int, int]] = {
     "mean_filter": (mean_filter, 1, 1),
@@ -190,6 +227,10 @@ APPS: dict[str, tuple[Callable[..., DataflowGraph], int, int]] = {
     "laplace": (laplace, 1, 1),
     "square": (square, 1, 1),
     "sobel": (sobel, 1, 1),
+    # not in Table I: REDUCE and EXPAND, the multi-rate stages (stage
+    # count as written: 21 REDUCE, 27 EXPAND, 18 Laplacian subtractions,
+    # 12 blends, 9 collapse adds)
+    "pyramid_blend": (pyramid_blend, 87, 7),
 }
 
 

@@ -22,6 +22,20 @@ Boundary semantics are zero-padding and are *bit-exact* across all
 three backends: inside the fused kernel, every stage output is masked
 to zero outside the logical image domain, which reproduces exactly the
 reference's per-stage ``jnp.pad`` behaviour at tile borders.
+
+A ``resample`` group (one REDUCE or EXPAND) is a ``pallas_call`` of
+its own whose grid walks the *output's* tiles: a REDUCE step reads a
+fine block twice the tile plus its halo, an EXPAND step a coarse block
+half the tile plus its halo.  The prefiltered fine plane of a REDUCE
+and the zero-stuffed plane of an EXPAND exist only in VMEM.  Rows are
+picked or placed with sublane-strided loads and stores; columns with a
+0/1 selection matmul at ``Precision.HIGHEST``, which moves each float32
+value exactly, so the taps still run in the reference's order and the
+result stays bit-exact.
+
+In :func:`lower_graph` each group's pad, call and crop run under
+``jax.named_scope(<app>_g<k>)``, so the wrapper ops around a kernel
+carry its name in the compiled program's op names.
 """
 from __future__ import annotations
 
@@ -35,8 +49,9 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.graph import (Channel, DataflowGraph, GraphError, Stage,
-                              _apply_stage_reference)
-from repro.core.schedule import FusionGroup, Schedule, build_schedule
+                              _apply_stage_reference, resample_up)
+from repro.core.schedule import (FusionGroup, Schedule, build_schedule,
+                                 split_root)
 from repro.core.vectorize import LANE, SUBLANE, TPUSpec, select_tile
 
 __all__ = ["lower_group", "lower_graph", "BACKENDS"]
@@ -108,8 +123,12 @@ def lower_group_pallas(group: FusionGroup, spec: TPUSpec, *,
     into and the scoped-VMEM limit handed to Mosaic.  The kernel is
     named after the group (``<app>_g<k>``).
     """
-    if group.is_trivial:
-        raise GraphError("cannot pallas-lower a custom/reduce group")
+    if not group.is_kernel:
+        raise GraphError(f"cannot pallas-lower a {group.kind} group")
+    if group.kind == "resample":
+        return _lower_resample_pallas(group, spec, interpret=interpret,
+                                      vector_factor=vector_factor,
+                                      valid_rows=valid_rows)
     tile = group.tile or select_tile(group, spec, vector_factor)[0]
     th, tw = tile
     H, W = group.stages[0].outputs[0].shape
@@ -117,19 +136,26 @@ def lower_group_pallas(group: FusionGroup, spec: TPUSpec, *,
     grid = (Hp // th, Wp // tw)
     rows = valid_rows if valid_rows is not None else (0, H)
 
+    # a split's copies are one buffer: an output that copies a group
+    # input is that input, and a plane fanned out is written once
+    inside = set(group.stages)
+    roots = {ch: split_root(ch, inside) for ch in group.outputs}
+    written = list(dict.fromkeys(r for r in roots.values()
+                                 if r not in group.inputs))
+
     blocks = [_input_block(tile, group.halo.get(ch, (0, 0)))
               for ch in group.inputs]
     in_specs = [pl.BlockSpec(tuple(pl.Element(s) for s in blk),
                              functools.partial(_in_index, th=th, tw=tw))
                 for blk in blocks]
     out_specs = [pl.BlockSpec((th, tw), lambda i, j: (i, j))
-                 for _ in group.outputs]
+                 for _ in written]
     out_shapes = [jax.ShapeDtypeStruct((Hp, Wp), ch.dtype)
-                  for ch in group.outputs]
+                  for ch in written]
 
     kernel = functools.partial(
-        _group_kernel, group=group, tile=tile, plane=(H, W),
-        n_in=len(group.inputs), rows=rows)
+        _group_kernel, group=group, outputs=written, tile=tile,
+        plane=(H, W), n_in=len(group.inputs), rows=rows)
 
     call = pl.pallas_call(
         kernel, grid=grid, in_specs=in_specs, out_specs=out_specs,
@@ -151,7 +177,9 @@ def lower_group_pallas(group: FusionGroup, spec: TPUSpec, *,
         outs = call(*ins)
         if not isinstance(outs, (tuple, list)):
             outs = (outs,)
-        return {ch: o[:H, :W] for ch, o in zip(group.outputs, outs)}
+        planes = {ch: o[:H, :W] for ch, o in zip(written, outs)}
+        return {ch: env_in[r] if r in group.inputs else planes[r]
+                for ch, r in roots.items()}
 
     return run
 
@@ -172,8 +200,8 @@ def _in_index(i, j, *, th, tw):
     return (i * th, j * tw)
 
 
-def _group_kernel(*refs, group: FusionGroup, tile: tuple[int, int],
-                  plane: tuple[int, int], n_in: int,
+def _group_kernel(*refs, group: FusionGroup, outputs: list[Channel],
+                  tile: tuple[int, int], plane: tuple[int, int], n_in: int,
                   rows: tuple[int, int]) -> None:
     th, tw = tile
     H, W = plane
@@ -202,8 +230,147 @@ def _group_kernel(*refs, group: FusionGroup, tile: tuple[int, int],
             # zero-padding semantics bit-exactly at tile borders.
             env[ch] = _mask_to_image(v, ch_halo, i, j, th, tw, rows, W)
 
-    for ch, ref in zip(group.outputs, out_refs):
+    for ch, ref in zip(outputs, out_refs):
         ref[...] = _crop(env[ch], halo.get(ch, (0, 0)), (0, 0), th, tw)
+
+
+# ----------------------------------------------------------------------
+# resampling kernels (REDUCE / EXPAND)
+# ----------------------------------------------------------------------
+def _lower_resample_pallas(group: FusionGroup, spec: TPUSpec, *,
+                           interpret: bool, vector_factor: int | None,
+                           valid_rows: tuple[int, int] | None) -> Callable:
+    """One ``pallas_call`` for a REDUCE or EXPAND stage.
+
+    The grid walks the output's tiles.  A REDUCE step reads the fine
+    block under its tile, ``(2th + 2hy, 2tw + 2hx)`` rounded to whole
+    (SUBLANE, LANE) tiles, as 128-lane column chunks (a sublane-strided
+    read needs a 128-lane base); an EXPAND step reads the coarse block
+    ``(th/2 + 2cy, tw/2 + 2cx)``, ``c = ceil(h/2)``.  The host pads
+    each input by its halo and out to the last block, as for a fused
+    group, and crops the output.
+    """
+    if valid_rows is not None:
+        raise GraphError("a resampling stage changes the plane's rows; "
+                         "it cannot run on a band of a larger plane")
+    st = group.stages[0]
+    src, dst = st.inputs[0], st.outputs[0]
+    tile = group.tile or select_tile(group, spec, vector_factor)[0]
+    th, tw = tile
+    H, W = dst.shape
+    Hp, Wp = _round_up(H, th), _round_up(W, tw)
+    hy, hx = st.halo
+    if resample_up(st):
+        cy, cx = -(-hy // 2), -(-hx // 2)
+        step = (th // 2, tw // 2)
+        block = _input_block(step, (cy, cx))
+        in_specs = [pl.BlockSpec(tuple(pl.Element(s) for s in block),
+                                 lambda i, j: (i * (th // 2), j * (tw // 2)))]
+        kernel = functools.partial(_expand_kernel, st=st, tile=tile,
+                                   offset=(2 * cy - hy, 2 * cx - hx))
+        scratch = [pltpu.VMEM((2 * block[0], LANE), jnp.float32)
+                   for _ in range(2 * block[1] // LANE)]
+        lead = (cy, cx)
+    else:
+        step = (2 * th, 2 * tw)
+        block = _input_block(step, (hy, hx))
+        n_chunks = block[1] // LANE
+        in_specs = [pl.BlockSpec((pl.Element(block[0]), pl.Element(LANE)),
+                                 functools.partial(_chunk_index, step=step,
+                                                   k=k))
+                    for k in range(n_chunks)]
+        kernel = functools.partial(_reduce_kernel, st=st, tile=tile,
+                                   n_in=n_chunks)
+        scratch = [pltpu.VMEM((st.window[0], th, block[1]), src.dtype)]
+        lead = (hy, hx)
+    grid = (Hp // th, Wp // tw)
+    call = pl.pallas_call(
+        kernel, grid=grid, in_specs=in_specs,
+        out_specs=pl.BlockSpec((th, tw), lambda i, j: (i, j)),
+        out_shape=jax.ShapeDtypeStruct((Hp, Wp), dst.dtype),
+        scratch_shapes=scratch, interpret=interpret, name=group.name,
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=int(spec.vmem_bytes)))
+    n_refs = len(in_specs)
+    # the padded input covers the last block: grid * step + the block's
+    # overhang, starting ``lead`` samples before the plane
+    rows_total = (grid[0] - 1) * step[0] + block[0]
+    cols_total = (grid[1] - 1) * step[1] + block[1]
+    h_in, w_in = src.shape
+
+    def run(env_in: dict[Channel, Any]) -> dict[Channel, Any]:
+        x = jnp.asarray(env_in[src], dtype=src.dtype)
+        x = jnp.pad(x, ((lead[0], rows_total - h_in - lead[0]),
+                        (lead[1], cols_total - w_in - lead[1])))
+        return {dst: call(*[x] * n_refs)[:H, :W]}
+
+    return run
+
+
+def _chunk_index(i, j, *, step, k):
+    # element offsets that Mosaic can prove whole (SUBLANE, LANE) tiles
+    return (i * step[0], (j * (step[1] // LANE) + k) * LANE)
+
+
+def _reduce_kernel(*refs, st: Stage, tile: tuple[int, int],
+                   n_in: int) -> None:
+    th, tw = tile
+    kh, kw = st.window
+    chunks, out_ref, rows_ref = refs[:n_in], refs[n_in], refs[n_in + 1]
+    # rows_ref[a]: the window's even rows from row offset a, every
+    # column.  The taps read them back from VMEM like any block, so
+    # they run exactly as in a stencil kernel.
+    for a in range(kh):
+        for k, c in enumerate(chunks):
+            rows_ref[a, :, k * LANE:(k + 1) * LANE] = \
+                c[pl.ds(a, th, stride=2), :]
+    views = [rows_ref[a][:, b:b + 2 * tw]
+             for a in range(kh) for b in range(kw)]
+    fine = st.fn(jnp.stack(views, axis=0))        # (th, 2tw)
+    out_ref[...] = _even_columns(fine).astype(out_ref.dtype)
+
+
+def _expand_kernel(in_ref, out_ref, *stuffed, st: Stage,
+                   tile: tuple[int, int], offset: tuple[int, int]) -> None:
+    th, tw = tile
+    kh, kw = st.window
+    dy, dx = offset
+    wide = _spread_columns(in_ref[...])          # x on the even columns
+    n = in_ref.shape[0]
+    for k, ref in enumerate(stuffed):            # and on the even rows
+        ref[...] = jnp.zeros_like(ref)
+        ref[pl.ds(0, n, stride=2), :] = wide[:, k * LANE:(k + 1) * LANE]
+    up = jnp.concatenate([ref[...] for ref in stuffed], axis=1)
+    up = up.astype(st.inputs[0].dtype)
+    views = [up[dy + a:dy + a + th, dx + b:dx + b + tw]
+             for a in range(kh) for b in range(kw)]
+    out_ref[...] = st.fn(jnp.stack(views, axis=0)).astype(out_ref.dtype)
+
+
+def _select(x, sel):
+    return jnp.dot(x.astype(jnp.float32), sel, precision=lax.Precision.HIGHEST,
+                   preferred_element_type=jnp.float32)
+
+
+def _even_columns(x):
+    """``x[:, ::2]``: a 0/1 selection matmul per 256 columns."""
+    r = lax.broadcasted_iota(jnp.int32, (2 * LANE, LANE), 0)
+    c = lax.broadcasted_iota(jnp.int32, (2 * LANE, LANE), 1)
+    sel = (r == 2 * c).astype(jnp.float32)
+    return jnp.concatenate(
+        [_select(x[:, m:m + 2 * LANE], sel)
+         for m in range(0, x.shape[1], 2 * LANE)], axis=1)
+
+
+def _spread_columns(x):
+    """``x`` on the even columns of a plane twice as wide, zeros on the
+    odd: a 0/1 selection matmul per 128 columns."""
+    r = lax.broadcasted_iota(jnp.int32, (LANE, 2 * LANE), 0)
+    c = lax.broadcasted_iota(jnp.int32, (LANE, 2 * LANE), 1)
+    sel = (c == 2 * r).astype(jnp.float32)
+    return jnp.concatenate(
+        [_select(x[:, m:m + LANE], sel) for m in range(0, x.shape[1], LANE)],
+        axis=1)
 
 
 def _stage_out_halo(st: Stage, halo: dict[Channel, tuple[int, int]]
@@ -314,7 +481,8 @@ def lower_graph(graph: DataflowGraph, backend="pallas",
         for ch in graph.graph_inputs:
             env[ch] = jnp.asarray(inputs[ch.name], dtype=ch.dtype)
         for fn, g in zip(fns, sched.groups):
-            outs = fn({ch: env[ch] for ch in g.inputs})
+            with jax.named_scope(g.name):
+                outs = fn({ch: env[ch] for ch in g.inputs})
             env.update(outs)
         return {ch.name: env[ch] for ch in graph.graph_outputs}
 

@@ -21,6 +21,7 @@ from typing import Any, Callable, Sequence
 
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
 
 __all__ = [
     "Channel",
@@ -29,6 +30,7 @@ __all__ = [
     "GraphError",
     "CycleError",
     "ChannelContractError",
+    "resample_up",
 ]
 
 
@@ -88,6 +90,13 @@ class Stage:
                     ``(kh*kw, *tile)`` holding the shifted views
                     (line-buffer analogue)
     - ``split``:    1 input -> k identical outputs (explicit fan-out)
+    - ``resample``: a change of rate by two, with window ``(kh, kw)``
+                    and the same ``fn(patches)`` as a stencil:
+                    REDUCE (output half the input's size) correlates
+                    the zero-padded plane and keeps the even rows and
+                    columns; EXPAND (output twice the size) puts the
+                    input on the even rows and columns of a zero plane
+                    and correlates that (see :func:`resample_up`)
     - ``reduce``:   global reduction ``fn(x) -> scalar/vector``
     - ``custom``:   opaque whole-array function (breaks fusion groups;
                     used to embed hand-written Pallas kernels)
@@ -238,6 +247,36 @@ class DataflowGraph:
                   window=window, **kw)
         return out
 
+    def pyr_down(self, x: Channel, fn: Callable,
+                 window: tuple[int, int] = (5, 5), name: str | None = None,
+                 **kw) -> Channel:
+        """REDUCE: correlate with ``fn(patches)``, keep even rows/cols.
+
+        The output is ``(H/2, W/2)``; an odd ``H`` or ``W`` is an error.
+        """
+        _check_resample("pyr_down", name, x, window, kw.get("meta"))
+        h, w = x.shape
+        if h % 2 or w % 2:
+            raise GraphError(
+                f"pyr_down stage {_stage_label(name)}: halves an even "
+                f"plane — expected even (H, W), got {x.shape} "
+                f"({x.name!r}){_src_note(kw.get('meta'))}")
+        out = self.channel((h // 2, w // 2), x.dtype)
+        self.task(name or self._fresh("pyr_down"), "resample", fn, [x],
+                  [out], window=window, **kw)
+        return out
+
+    def pyr_up(self, x: Channel, fn: Callable,
+               window: tuple[int, int] = (5, 5), name: str | None = None,
+               **kw) -> Channel:
+        """EXPAND: zero-stuff to ``(2H, 2W)``, correlate with ``fn``."""
+        _check_resample("pyr_up", name, x, window, kw.get("meta"))
+        h, w = x.shape
+        out = self.channel((2 * h, 2 * w), x.dtype)
+        self.task(name or self._fresh("pyr_up"), "resample", fn, [x],
+                  [out], window=window, **kw)
+        return out
+
     def split(self, x: Channel, k: int = 2, name: str | None = None,
               **kw) -> tuple[Channel, ...]:
         """``split_image``: explicit fan-out of a channel to k copies."""
@@ -301,6 +340,9 @@ class DataflowGraph:
                 raise ChannelContractError(
                     f"channel {ch.name!r} cannot be both graph input and "
                     f"output")
+        for st in self.stages:
+            if st.kind == "resample":
+                _check_resample_shapes(st)
         self.toposort()  # raises CycleError on cycles
 
     def toposort(self) -> list[Stage]:
@@ -418,6 +460,44 @@ def _src_note(meta: dict | None) -> str:
     return f" (traced at {src})" if src else ""
 
 
+def _check_resample(op: str, name: str | None, x: Channel,
+                    window: tuple[int, int], meta: dict | None) -> None:
+    if window[0] % 2 != 1 or window[1] % 2 != 1:
+        raise GraphError(
+            f"{op} stage {_stage_label(name)}: window must be odd — "
+            f"expected odd (kh, kw), got {window}{_src_note(meta)}")
+    if len(x.shape) != 2:
+        raise GraphError(
+            f"{op} stage {_stage_label(name)}: expects a 2-D plane, got "
+            f"input {x.name!r} of shape {x.shape}{_src_note(meta)}")
+    if not jnp.issubdtype(x.dtype, jnp.floating):
+        raise GraphError(
+            f"{op} stage {_stage_label(name)}: resamples floating planes, "
+            f"got {np.dtype(x.dtype).name} ({x.name!r}){_src_note(meta)}")
+
+
+def _check_resample_shapes(st: Stage) -> None:
+    """The shape rule: one 2-D input and output, sizes in a ratio of 2."""
+    if len(st.inputs) != 1 or len(st.outputs) != 1:
+        raise GraphError(f"resample stage {st.name!r} takes one input and "
+                         f"gives one output{_src_note(st.meta)}")
+    if len(st.inputs[0].shape) != 2 or not jnp.issubdtype(
+            st.inputs[0].dtype, jnp.floating):
+        raise GraphError(f"resample stage {st.name!r} takes a 2-D floating "
+                         f"plane{_src_note(st.meta)}")
+    (h, w), (oh, ow) = st.inputs[0].shape, st.outputs[0].shape
+    if (oh, ow) not in ((2 * h, 2 * w), (h / 2, w / 2)):
+        raise GraphError(
+            f"resample stage {st.name!r}: output {(oh, ow)} is neither "
+            f"half nor twice its input {(h, w)}{_src_note(st.meta)}")
+
+
+def resample_up(st: Stage) -> bool:
+    """True for an EXPAND stage (output twice its input's size), False
+    for a REDUCE."""
+    return st.outputs[0].shape[0] > st.inputs[0].shape[0]
+
+
 def _fn_fingerprint(fn: Any, _depth: int = 0) -> str:
     """Best-effort structural fingerprint of a stage function.
 
@@ -503,6 +583,21 @@ def extract_patches(x: jnp.ndarray, window: tuple[int, int]) -> jnp.ndarray:
     return jnp.stack(views, axis=0)
 
 
+def resample_reference(x: jnp.ndarray, fn: Callable,
+                       window: tuple[int, int], up: bool) -> jnp.ndarray:
+    """Reference semantics of a ``resample`` stage.
+
+    REDUCE: ``fn`` over the zero-padded plane's windows, then the even
+    rows and columns.  EXPAND: ``x`` on the even rows and columns of a
+    zero ``(2H, 2W)`` plane, then ``fn`` over its windows.
+    """
+    if up:
+        # zeros between the samples and after the last: x at the evens
+        x = lax.pad(x, jnp.zeros((), x.dtype), ((0, 1, 1), (0, 1, 1)))
+        return fn(extract_patches(x, window))
+    return fn(extract_patches(x, window))[::2, ::2]
+
+
 def _apply_stage_reference(st: Stage, vals: list[Any]) -> list[Any]:
     if st.kind == "point":
         return [st.fn(vals[0])]
@@ -512,6 +607,9 @@ def _apply_stage_reference(st: Stage, vals: list[Any]) -> list[Any]:
         return [st.fn(extract_patches(vals[0], st.window))]
     if st.kind == "split":
         return [vals[0] for _ in st.outputs]
+    if st.kind == "resample":
+        return [resample_reference(vals[0], st.fn, st.window,
+                                   resample_up(st))]
     if st.kind == "reduce":
         return [st.fn(vals[0])]
     if st.kind == "custom":

@@ -29,8 +29,8 @@ from repro.core.graph import DataflowGraph
 from repro.frontend.lib import (GAUSS3, GAUSS5, JACOBI3, LAPLACE3, MEAN5,
                                 SOBEL_X, SOBEL_Y, add, bilateral, conv_taps,
                                 harris_response, lam_min, lk_vx, lk_vy,
-                                luma_rec601, mul, scale, sobel_mag, square,
-                                sub)
+                                luma_rec601, mask_blend, mul, pyr_down_taps,
+                                pyr_up_taps, scale, sobel_mag, square, sub)
 
 __all__ = ["HAND_BUILT"]
 
@@ -189,6 +189,42 @@ def optical_flow_lk(h: int, w: int, eps: float = 1e-3) -> DataflowGraph:
     return g
 
 
+def pyramid_blend(h: int, w: int, levels: int = 4) -> DataflowGraph:
+    """Burt & Adelson multi-band blending: 7 inputs, 3 outputs, 87
+    stages; the fan-out of every pyramid level is left to auto-split."""
+    mix = mask_blend(255.0).fn
+    g = DataflowGraph("pyramid_blend")
+    a = {c: g.input(f"a_{c}", (h, w)) for c in "rgb"}
+    b = {c: g.input(f"b_{c}", (h, w)) for c in "rgb"}
+    mask = g.input("mask", (h, w))
+
+    def gaussian(x, tag):
+        pyr = [x]
+        for lv in range(1, levels):
+            pyr.append(g.pyr_down(pyr[-1], pyr_down_taps,
+                                  name=f"reduce_{tag}{lv}"))
+        return pyr
+
+    gm = gaussian(mask, "m")
+    for c in "rgb":
+        ga, gb = gaussian(a[c], f"a{c}"), gaussian(b[c], f"b{c}")
+        s = g.pointn([gm[-1], ga[-1], gb[-1]], mix,
+                     name=f"blend_{c}{levels - 1}")
+        for lv in reversed(range(levels - 1)):
+            la = g.point2(ga[lv], g.pyr_up(ga[lv + 1], pyr_up_taps,
+                                           name=f"expand_a{c}{lv}"),
+                          sub, name=f"lap_a{c}{lv}")
+            lb = g.point2(gb[lv], g.pyr_up(gb[lv + 1], pyr_up_taps,
+                                           name=f"expand_b{c}{lv}"),
+                          sub, name=f"lap_b{c}{lv}")
+            s = g.point2(g.pointn([gm[lv], la, lb], mix,
+                                  name=f"blend_{c}{lv}"),
+                         g.pyr_up(s, pyr_up_taps, name=f"expand_s{c}{lv}"),
+                         add, name=f"collapse_{c}{lv}")
+        g.output(s, f"out_{c}")
+    return g
+
+
 #: name -> hand-built builder (the oracle twin of ``repro.core.apps.APPS``)
 HAND_BUILT: dict[str, Callable[..., DataflowGraph]] = {
     "mean_filter": mean_filter,
@@ -204,4 +240,5 @@ HAND_BUILT: dict[str, Callable[..., DataflowGraph]] = {
     "laplace": laplace,
     "square": square_app,
     "sobel": sobel,
+    "pyramid_blend": pyramid_blend,
 }

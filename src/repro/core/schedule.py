@@ -18,6 +18,8 @@ scheduler
    Diamond- and branch-shaped DAGs therefore collapse into ONE fused
    streaming kernel instead of fragmenting into per-branch chains;
    ``custom`` and ``reduce`` stages stay group-breaking singletons,
+   and so does each ``resample`` stage (REDUCE / EXPAND), which
+   becomes a streaming kernel of its own,
 4. computes the *cumulative halo* each channel must carry so that
    downstream stencils have their windows available inside the fused
    kernel (the line-buffer analysis),
@@ -28,13 +30,15 @@ scheduler
 """
 from __future__ import annotations
 
+import collections
 import dataclasses
 import re
 from typing import Sequence
 
 import numpy as np
 
-from repro.core.graph import Channel, DataflowGraph, GraphError, Stage
+from repro.core.graph import (Channel, DataflowGraph, GraphError, Stage,
+                              resample_up)
 from repro.core.simulate import TaskTiming, analytic_latency
 from repro.core.transform import Pass, PassPipeline, default_pipeline
 from repro.obs.tracer import maybe_span
@@ -63,7 +67,8 @@ class FusionGroup:
     halo: dict[Channel, tuple[int, int]]
     #: selected tile (th, tw); filled in by the vectorizer
     tile: tuple[int, int] | None = None
-    #: vector factor behind the selected tile (tw == 128 * vector_factor);
+    #: vector factor behind the selected tile (tw == 128 * vector_factor,
+    #: 256 * for an EXPAND, see :meth:`tile_unit`);
     #: set by choose_tile/select_tile alongside ``tile``
     vector_factor: int | None = None
     #: why this tile was chosen: "model" (analytic sweep), "forced"
@@ -76,9 +81,48 @@ class FusionGroup:
     name: str | None = None
 
     @property
-    def is_trivial(self) -> bool:
-        """Groups of one non-fusible stage (custom / reduce)."""
-        return len(self.stages) == 1 and self.stages[0].kind not in FUSIBLE_KINDS
+    def kind(self) -> str:
+        """What the group lowers to on a fused backend.
+
+        ``dataflow``: one fused streaming kernel; ``resample``: one
+        REDUCE or EXPAND, a streaming kernel of its own; ``alias``:
+        only ``split`` stages, no kernel at all (every reader takes the
+        one HBM buffer); ``custom``: one custom/reduce stage, run as
+        whole-array jnp.
+        """
+        first = self.stages[0].kind
+        if len(self.stages) == 1 and first not in FUSIBLE_KINDS:
+            return "resample" if first == "resample" else "custom"
+        if all(st.kind == "split" for st in self.stages):
+            return "alias"
+        return "dataflow"
+
+    @property
+    def is_kernel(self) -> bool:
+        """Whether a fused backend lowers the group to a kernel."""
+        return self.kind in ("dataflow", "resample")
+
+    def tile_unit(self, sublane: int, lane: int) -> tuple[int, int]:
+        """The step a tile's (th, tw) grows by.  An EXPAND's coarse
+        input blocks start at (th/2, tw/2) multiples, which Mosaic takes
+        only on whole (sublane, lane) tiles, so its unit is doubled."""
+        if self.kind == "resample" and resample_up(self.stages[0]):
+            return 2 * sublane, 2 * lane
+        return sublane, lane
+
+    def in_window(self, ch: Channel, tile: tuple[int, int]
+                  ) -> tuple[int, int]:
+        """The window of input ``ch`` one grid step reads for an output
+        tile: the tile plus the channel's halo on each side; for a
+        REDUCE twice the tile plus the halo, for an EXPAND half the tile
+        plus the halo in coarse samples (rounded up)."""
+        th, tw = tile
+        hy, hx = self.halo.get(ch, (0, 0))
+        if self.kind == "resample":
+            if resample_up(self.stages[0]):
+                return th // 2 + 2 * -(-hy // 2), tw // 2 + 2 * -(-hx // 2)
+            return 2 * th + 2 * hy, 2 * tw + 2 * hx
+        return th + 2 * hy, tw + 2 * hx
 
     def vmem_bytes(self, tile: tuple[int, int] | None = None) -> int:
         """Double-buffered VMEM working set for a candidate tile.
@@ -86,22 +130,35 @@ class FusionGroup:
         Every channel live inside the kernel holds an expanded tile of
         ``(th + 2hy, tw + 2hx)`` elements at FIFO depth ``ch.depth``;
         stencil stages additionally materialize their ``kh*kw`` shifted
-        views (the register-file cost of the window).
+        views (the register-file cost of the window).  A resampling
+        stage reads its input window (:meth:`in_window`) and holds its
+        ``kh*kw`` views at fine resolution: a REDUCE's on the even rows
+        only, an EXPAND's beside the zero-stuffed window.
         """
         tile = tile or self.tile
         if tile is None:
             raise GraphError("no tile selected for group")
         th, tw = tile
         total = 0
-        for ch in self.inputs + self.outputs + self.internal:
+        for ch in self.inputs:
+            rows, cols = self.in_window(ch, tile)
+            total += rows * cols * _itemsize(ch) * ch.depth
+        for ch in self.outputs + self.internal:
             hy, hx = self.halo.get(ch, (0, 0))
             total += (th + 2 * hy) * (tw + 2 * hx) * _itemsize(ch) * ch.depth
         for st in self.stages:
+            kh, kw = st.window
+            out = st.outputs[0] if st.outputs else None
             if st.kind == "stencil":
-                kh, kw = st.window
-                out = st.outputs[0]
                 hy, hx = self.halo.get(out, (0, 0))
                 total += kh * kw * (th + 2 * hy) * (tw + 2 * hx) * _itemsize(out)
+            elif st.kind == "resample":
+                if resample_up(st):
+                    rows, cols = self.in_window(st.inputs[0], tile)
+                    total += (kh * kw * th * tw
+                              + 4 * rows * cols) * _itemsize(out)
+                else:
+                    total += kh * kw * th * 2 * tw * _itemsize(out)
         return total
 
 
@@ -144,6 +201,33 @@ class Schedule:
         from repro.core.vectorize import schedule_features
         return schedule_features(self, items=items)
 
+    def group_counts(self) -> dict[str, int]:
+        """How many groups of each :attr:`FusionGroup.kind`."""
+        return dict(sorted(collections.Counter(
+            g.kind for g in self.groups).items()))
+
+    def interstage_bytes(self) -> int:
+        """HBM bytes one frame moves from group to group.
+
+        Each plane a group writes for another is counted once as it is
+        written and once for every group input that reads it.  A
+        split's copies are the one buffer they copy (a group of splits
+        moves nothing, and a fused kernel writes a plane once however
+        many splits fan it out), the app's own inputs are never written
+        by a group, and the write of an app output is the app's own I/O.
+        """
+        read, written = 0, set()
+        for g in self.groups:
+            if g.kind == "alias":
+                continue
+            for ch in g.inputs:
+                buf = split_root(ch)
+                if not buf.is_graph_input:
+                    read += buf.nbytes
+                    written.add(buf)
+        return read + sum(b.nbytes for b in written
+                          if not b.is_graph_output)
+
     def describe(self) -> str:
         """Render the schedule: kernels, FIFOs, tiles + provenance.
 
@@ -156,9 +240,8 @@ class Schedule:
         lines = [f"schedule for {self.graph.name!r}: "
                  f"{len(self.order)} stages -> {len(self.groups)} kernels"]
         for gi, g in enumerate(self.groups):
-            kind = "custom" if g.is_trivial else "dataflow"
             names = ",".join(s.name for s in g.stages)
-            lines.append(f"  kernel[{gi}] ({kind}): {names}")
+            lines.append(f"  kernel[{gi}] ({g.kind}): {names}")
             lines.append(f"    inputs={[c.name for c in g.inputs]} "
                          f"outputs={[c.name for c in g.outputs]} "
                          f"fifo={[c.name for c in g.internal]}")
@@ -166,6 +249,9 @@ class Schedule:
                 lines.append(f"    tile={g.tile} "
                              f"vector_factor={g.vector_factor} "
                              f"via {g.tile_source}")
+        lines.append("  groups by kind: " + ", ".join(
+            f"{k}={n}" for k, n in self.group_counts().items())
+            + f"; inter-group HBM {self.interstage_bytes()} B a frame")
         lines.append("  bundles: " + ", ".join(
             f"{c.name}->mem{b}" for c, b in self.bundles.items()))
         if self.diagnostics:
@@ -265,7 +351,7 @@ def _select_tiles(groups: list[FusionGroup], spec,
                      f"analytic sweep")
         group_vf = None
     for gi, g in enumerate(groups):
-        if g.is_trivial:
+        if not g.is_kernel:
             continue
         forced = vector_factor
         g.tile_source = "forced" if vector_factor is not None else "model"
@@ -308,6 +394,15 @@ def _select_tiles(groups: list[FusionGroup], spec,
 # ----------------------------------------------------------------------
 # convex-subgraph DAG fusion
 # ----------------------------------------------------------------------
+def split_root(ch: Channel, within: set | None = None) -> Channel:
+    """The buffer ``ch`` reads: through any chain of splits (only those
+    in ``within``, when given), the channel they copy."""
+    while (ch.producer is not None and ch.producer.kind == "split"
+           and (within is None or ch.producer in within)):
+        ch = ch.producer.inputs[0]
+    return ch
+
+
 def _is_fusible(st: Stage) -> bool:
     return (st.kind in FUSIBLE_KINDS
             and all(len(c.shape) == 2 for c in st.inputs + st.outputs))

@@ -7,7 +7,8 @@ streamed tile so its minor dimension is a multiple of the 128-lane VPU
 the double-buffered working set of the whole fused group fits in VMEM.
 
 The *vector factor* maps to how many 128-lane vectors a tile row
-carries (``tw == 128 * vector_factor``); the *burst length* maps to
+carries (``tw == 128 * vector_factor``, twice that for an EXPAND
+kernel, see :meth:`FusionGroup.tile_unit`); the *burst length* maps to
 the tile byte count per DMA (bigger tiles == longer HBM bursts ==
 better DMA efficiency, up to the VMEM budget).
 
@@ -110,6 +111,7 @@ def choose_tile(group: FusionGroup, spec: TPUSpec | None = None,
     if len(shape) != 2:
         raise ValueError(f"generic fusion tiles 2-D planes, got {shape}")
     H, W = shape
+    sublane, lane = group.tile_unit(sublane, lane)
     tw = lane * vector_factor
     # clamp BEFORE committing to the factor: a tile wider than the
     # lane-rounded plane only streams padding, and max_tile is a hard
@@ -127,7 +129,7 @@ def choose_tile(group: FusionGroup, spec: TPUSpec | None = None,
 
     while group.vmem_bytes((th, tw)) > spec.vmem_bytes:
         if th > sublane:
-            th = max(sublane, th // 2)
+            th = max(sublane, th // 2 // sublane * sublane)
         else:
             raise ValueError(
                 f"group {[s.name for s in group.stages]} cannot fit VMEM "
@@ -158,12 +160,7 @@ def modeled_plane_time(group: FusionGroup, tile: tuple[int, int],
     th, tw = tile
     H, W = group.stages[0].outputs[0].shape
     grid = (_round_up(H, th) // th) * (_round_up(W, tw) // tw)
-    bytes_step = 0
-    for ch in group.inputs:
-        hy, hx = group.halo.get(ch, (0, 0))
-        bytes_step += (th + 2 * hy) * (tw + 2 * hx) * np.dtype(ch.dtype).itemsize
-    for ch in group.outputs:
-        bytes_step += th * tw * np.dtype(ch.dtype).itemsize
+    bytes_step = _bytes_step(group, tile)
     dma_s = bytes_step / spec.hbm_bw
     scale = dict(getattr(spec, "ii_scale", ()) or ())
     if scale:
@@ -172,6 +169,19 @@ def modeled_plane_time(group: FusionGroup, tile: tuple[int, int],
         steps = sum(st.ii for st in group.stages)
     compute_s = steps * th * tw / spec.clock_hz
     return grid * (spec.step_overhead_s + max(dma_s, compute_s))
+
+
+def _bytes_step(group: FusionGroup, tile: tuple[int, int]) -> int:
+    """HBM bytes one grid step moves: each input's window
+    (:meth:`FusionGroup.in_window`) in, each output tile out."""
+    th, tw = tile
+    total = 0
+    for ch in group.inputs:
+        rows, cols = group.in_window(ch, tile)
+        total += rows * cols * np.dtype(ch.dtype).itemsize
+    for ch in group.outputs:
+        total += th * tw * np.dtype(ch.dtype).itemsize
+    return total
 
 
 def plane_features(group: FusionGroup, tile: tuple[int, int]) -> dict:
@@ -195,12 +205,7 @@ def plane_features(group: FusionGroup, tile: tuple[int, int]) -> dict:
     th, tw = tile
     H, W = group.stages[0].outputs[0].shape
     grid = (_round_up(H, th) // th) * (_round_up(W, tw) // tw)
-    bytes_step = 0
-    for ch in group.inputs:
-        hy, hx = group.halo.get(ch, (0, 0))
-        bytes_step += (th + 2 * hy) * (tw + 2 * hx) * np.dtype(ch.dtype).itemsize
-    for ch in group.outputs:
-        bytes_step += th * tw * np.dtype(ch.dtype).itemsize
+    bytes_step = _bytes_step(group, tile)
     steps: dict[str, float] = {}
     for st in group.stages:
         steps[st.kind] = steps.get(st.kind, 0.0) + float(st.ii)
@@ -218,7 +223,7 @@ def schedule_features(schedule, items: int = 1) -> dict:
     a drift row stays self-describing.
     """
     groups = [plane_features(g, g.tile) for g in schedule.groups
-              if not g.is_trivial and g.tile is not None]
+              if g.is_kernel and g.tile is not None]
     feats = {"groups": groups}
     if items != 1:
         feats["items"] = int(items)
@@ -252,6 +257,7 @@ def sweep_vector_factor(group: FusionGroup, spec: TPUSpec | None = None,
             return records
     shape = group.stages[0].outputs[0].shape
     H, W = shape
+    lane = group.tile_unit(1, lane)[1]
     cap_tw = min(_round_up(W, lane), max(lane, (max_tile[1] // lane) * lane))
     if candidates is None:
         candidates = tuple(range(1, cap_tw // lane + 2))
@@ -341,7 +347,7 @@ def modeled_schedule_time(schedule, spec: TPUSpec = V5E) -> float:
     """
     total = 0.0
     for g in schedule.groups:
-        if g.is_trivial or g.tile is None:
+        if not g.is_kernel or g.tile is None:
             continue
         total += modeled_plane_time(g, g.tile, spec)
     return total
@@ -351,15 +357,14 @@ def vmem_report(group: FusionGroup) -> dict:
     th, tw = group.tile
     return {
         "tile": group.tile,
-        "vector_factor": tw // LANE,
+        "vector_factor": tw // group.tile_unit(SUBLANE, LANE)[1],
         "vmem_bytes": group.vmem_bytes(),
         "n_channels": len(group.inputs) + len(group.outputs)
         + len(group.internal),
         "burst_bytes": max(
-            (th + 2 * hy) * (tw + 2 * hx)
-            * np.dtype(ch.dtype).itemsize
+            rows * cols * np.dtype(ch.dtype).itemsize
             for ch in group.inputs
-            for hy, hx in [group.halo.get(ch, (0, 0))]
+            for rows, cols in [group.in_window(ch, group.tile)]
         ) if group.inputs else 0,
     }
 

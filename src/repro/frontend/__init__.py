@@ -5,9 +5,10 @@ hand-assembling a :class:`~repro.core.graph.DataflowGraph` (naming
 channels, inserting splits, minding the single-reader contract), the
 user writes an ordinary Python function over arrays and the frontend
 *extracts* the graph — operator overloading records point ops, the
-library ops (:func:`conv`, :func:`window`, :func:`reduce`,
-:func:`where`, :func:`custom`) record the structured stages, and the
-standard pass pipeline canonicalizes the result.
+library ops (:func:`conv`, :func:`window`, :func:`pyr_down`,
+:func:`pyr_up`, :func:`reduce`, :func:`where`, :func:`custom`) record
+the structured stages, and the standard pass pipeline canonicalizes
+the result.
 
 Conventional use::
 
@@ -32,14 +33,15 @@ from repro.frontend.diagnostics import (TraceControlFlowError,
 from repro.frontend.tracer import (DataflowFunction, InputSpec, Plane,
                                    PointFn, dataflow_fn, pointfn, trace)
 from repro.frontend.ops import (abs, conv, cos, custom, exp, log, maximum,
-                                minimum, reduce, select, sign, sin, spec,
-                                sqrt, tanh, where, window)
+                                minimum, pyr_down, pyr_up, reduce, select,
+                                sign, sin, spec, sqrt, tanh, where, window)
 from repro.frontend import lib
 
 __all__ = [
     "Plane", "InputSpec", "PointFn", "pointfn", "trace", "dataflow_fn",
     "DataflowFunction", "spec",
-    "conv", "window", "reduce", "where", "select", "custom",
+    "conv", "window", "pyr_down", "pyr_up", "reduce", "where", "select",
+    "custom",
     "sqrt", "exp", "log", "abs", "tanh", "sin", "cos", "sign",
     "maximum", "minimum", "lib",
     "TraceError", "TraceShapeError", "TraceDtypeError",

@@ -37,9 +37,10 @@ from repro.frontend.tracer import (add, div, mul, neg, offset, pointfn,
 
 __all__ = [
     "GAUSS3", "GAUSS5", "MEAN5", "SOBEL_X", "SOBEL_Y", "LAPLACE3",
-    "JACOBI3",
-    "conv_taps", "sobel_mag", "bilateral",
+    "JACOBI3", "PYR_UP5",
+    "conv_taps", "sobel_mag", "bilateral", "pyr_down_taps", "pyr_up_taps",
     "luma_rec601", "harris_response", "lam_min", "lk_vx", "lk_vy",
+    "mask_blend",
     # canonical elementwise ops (tracer re-exports)
     "add", "sub", "mul", "div", "square", "neg", "offset", "scale",
     "subc", "powc",
@@ -56,6 +57,9 @@ SOBEL_X = np.array([[-1, 0, 1], [-2, 0, 2], [-1, 0, 1]], np.float32)
 SOBEL_Y = SOBEL_X.T.copy()
 LAPLACE3 = np.array([[0, 1, 0], [1, -4, 1], [0, 1, 0]], np.float32)
 JACOBI3 = np.array([[0, 1, 0], [1, 0, 1], [0, 1, 0]], np.float32) / 4.0
+#: EXPAND's taps (Burt & Adelson): GAUSS5 times 4, since three of every
+#: four samples of the zero-stuffed plane are zeros
+PYR_UP5 = GAUSS5 * 4.0
 
 
 def conv_taps(weights: np.ndarray) -> Callable:
@@ -112,6 +116,12 @@ def bilateral(sigma_s: float = 2.0, sigma_r: float = 0.25) -> Callable:
     return fn
 
 
+#: the patch functions of REDUCE and EXPAND (OpenCV's ``pyrDown`` and
+#: ``pyrUp`` filters), shared by the frontend and the hand-built graphs
+pyr_down_taps = conv_taps(GAUSS5)
+pyr_up_taps = conv_taps(PYR_UP5)
+
+
 # ----------------------------------------------------------------------
 # pointwise formulas
 # ----------------------------------------------------------------------
@@ -136,6 +146,17 @@ def lam_min(a, c, b):
     tr2 = (a + c) * 0.5
     det = a * c - b * b
     return tr2 - jnp.sqrt(jnp.maximum(tr2 * tr2 - det, 0.0) + 1e-12)
+
+
+def mask_blend(levels: float = 255.0):
+    """Mix two planes under a mask plane of ``levels`` + 1 steps:
+    ``w*a + (1-w)*b`` with ``w = m/levels``."""
+    @pointfn
+    def mix(m, a, b):
+        w = m / levels
+        return w * a + (1.0 - w) * b
+
+    return mix
 
 
 def lk_vx(eps: float = 1e-3):

@@ -11,6 +11,8 @@ frontend op           stage kind (``repro.core.graph``)
 ``+ - * /`` etc.      ``point`` / ``pointN``
 :func:`conv`          ``stencil`` (taps unrolled, zeros elided)
 :func:`window`        ``stencil`` (arbitrary local operator)
+:func:`pyr_down`      ``resample`` (REDUCE: blur, keep even rows/cols)
+:func:`pyr_up`        ``resample`` (EXPAND: zero-stuff ×2, blur)
 :func:`reduce`        ``reduce``  (global, group-breaking)
 :func:`where`         ``pointN`` select on a bool Plane
 :func:`custom`        ``custom``  (opaque; embeds hand kernels)
@@ -38,15 +40,17 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core.graph import resample_reference
 from repro.frontend.diagnostics import (TraceDtypeError, TraceError,
                                         TraceShapeError, user_src)
-from repro.frontend.lib import conv_taps
+from repro.frontend.lib import conv_taps, pyr_down_taps, pyr_up_taps
 from repro.frontend.tracer import (InputSpec, Plane, dataflow_fn, pointfn,
                                    trace)
 
 __all__ = [
     "spec", "trace", "dataflow_fn",
-    "conv", "window", "reduce", "where", "select", "custom",
+    "conv", "window", "pyr_down", "pyr_up", "reduce", "where", "select",
+    "custom",
     "sqrt", "exp", "log", "abs", "tanh", "sin", "cos", "sign",
     "maximum", "minimum",
 ]
@@ -120,6 +124,59 @@ def window(x, win: tuple[int, int], fn: Callable, *,
     return x.tracer.record(
         "stencil", [x], fn, key=("window", id(fn)), window=(kh, kw),
         dtype=dtype, name=name, ii=ii, fill=fill)
+
+
+# ----------------------------------------------------------------------
+# resampling ops (Gaussian and Laplacian pyramids)
+# ----------------------------------------------------------------------
+def pyr_down(x, *, name: str | None = None):
+    """REDUCE (Burt & Adelson): ``(H, W) -> (H/2, W/2)``.
+
+    Correlates the zero-padded plane with the 5x5 binomial
+    ``GAUSS5`` and keeps the even rows and columns — OpenCV's
+    ``pyrDown`` with the pipeline's zero boundary.  ``H`` and ``W``
+    must be even.  On a non-Plane array it just computes.
+    """
+    if not isinstance(x, Plane):
+        return resample_reference(jnp.asarray(x), pyr_down_taps, (5, 5),
+                                  up=False)
+    _check_resample_input("pyr_down", x)
+    if x.shape[0] % 2 or x.shape[1] % 2:
+        raise TraceShapeError(
+            f"pyr_down halves an even plane, got shape {x.shape}",
+            user_src())
+    h, w = x.shape
+    return x.tracer.record("resample", [x], pyr_down_taps, key=("pyr_down",),
+                           window=(5, 5), out_shape=(h // 2, w // 2),
+                           name=name)
+
+
+def pyr_up(x, *, name: str | None = None):
+    """EXPAND (Burt & Adelson): ``(H, W) -> (2H, 2W)``.
+
+    Puts ``x`` on the even rows and columns of a zero plane twice as
+    large and correlates that with ``PYR_UP5`` (``GAUSS5`` times 4) —
+    OpenCV's ``pyrUp`` with the pipeline's zero boundary.  The stuffed
+    plane exists only inside the kernel.  On a non-Plane array it just
+    computes.
+    """
+    if not isinstance(x, Plane):
+        return resample_reference(jnp.asarray(x), pyr_up_taps, (5, 5),
+                                  up=True)
+    _check_resample_input("pyr_up", x)
+    h, w = x.shape
+    return x.tracer.record("resample", [x], pyr_up_taps, key=("pyr_up",),
+                           window=(5, 5), out_shape=(2 * h, 2 * w),
+                           name=name)
+
+
+def _check_resample_input(op: str, x: Plane) -> None:
+    _check_stencil_input(op, x)
+    if not jnp.issubdtype(x.dtype, jnp.floating):
+        raise TraceDtypeError(
+            f"{op} resamples floating planes, got "
+            f"{np.dtype(x.dtype).name}; convert with .astype first",
+            user_src())
 
 
 def _check_stencil_input(op: str, x: Plane) -> None:

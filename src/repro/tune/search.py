@@ -186,7 +186,7 @@ def _model_config(graph, spec: TPUSpec, max_tile: tuple[int, int],
     """The analytic pick under one (max_tile, budget) point, as a config."""
     sched = build_schedule(graph, spec=scale_spec(spec, vmem_fraction),
                            max_tile=max_tile, **build_kwargs)
-    vfs = tuple(None if g.is_trivial else g.vector_factor
+    vfs = tuple(g.vector_factor if g.is_kernel else None
                 for g in sched.groups)
     return (ScheduleConfig(group_vf=vfs, max_tile=max_tile,
                            vmem_fraction=vmem_fraction), sched)
@@ -338,7 +338,7 @@ def tune_graph(graph, backend="pallas", *,
                                    device_kind, interpret=interpret,
                                    context=context)
     tunable = [i for i, g in enumerate(baseline_sched.groups)
-               if not g.is_trivial]
+               if g.is_kernel]
     drift_sig = baseline_sched.graph.signature()
     drift_shapes = [list(c.shape)
                     for c in baseline_sched.graph.graph_inputs]

@@ -10,6 +10,7 @@ test of this file has started (only one process may load the TPU
 library at a time).
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -24,6 +25,8 @@ BATCHED_APPS = ("gaussian_blur", "mean_filter", "filter_chain", "harris",
                 "optical_flow_lk")
 PLANE = (1080, 1920)
 BATCH = 8
+#: the multi-rate app at its benchmark size (PolyMage's)
+PLANE_PYR = (2048, 2048)
 #: the four-chip replication paths at 3840x2160
 REPLICATED_APPS = ("gaussian_blur", "filter_chain")
 PLANE_4K = (2160, 3840)
@@ -67,13 +70,28 @@ def _compiled_app(name: str, plane: tuple[int, int]):
 def test_batched_fused_program_compiles_for_v5e(name, one_chip,
                                                 no_persistent_cache):
     app = _compiled_app(name, PLANE)
-    kernels = sum(not g.is_trivial for g in app.schedule.groups)
+    kernels = sum(g.is_kernel for g in app.schedule.groups)
     args = [jax.ShapeDtypeStruct((BATCH,) + PLANE, jnp.float32,
                                  sharding=one_chip)
             for _ in app.input_names]
     hlo = jax.jit(jax.vmap(app.fn)).lower(*args).compile().as_text()
     assert kernels > 0
     assert hlo.count(KERNEL) == kernels
+
+
+def test_pyramid_blend_compiles_for_v5e(one_chip, no_persistent_cache):
+    """The multi-rate app at its benchmark size: one device program
+    holding one Mosaic kernel per kernel group of the schedule, the 48
+    resampling kernels among them, each under its group's name."""
+    app = _compiled_app("pyramid_blend", PLANE_PYR)
+    kernels = [g for g in app.schedule.groups if g.is_kernel]
+    args = [jax.ShapeDtypeStruct(PLANE_PYR, jnp.float32, sharding=one_chip)
+            for _ in app.input_names]
+    hlo = jax.jit(app.fn).lower(*args).compile().as_text()
+    assert sum(g.kind == "resample" for g in kernels) == 48
+    assert hlo.count(KERNEL) == len(kernels)
+    for g in kernels:
+        assert re.search(rf"%{g.name}(\.\d+)? = .*{KERNEL}", hlo), g.name
 
 
 @pytest.mark.parametrize("name", REPLICATED_APPS)
